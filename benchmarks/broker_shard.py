@@ -1,4 +1,4 @@
-"""Sharded vs single-device broker flush throughput on an 8-device mesh.
+"""Sharded vs single-device broker flush throughput on a 4-device mesh.
 
 Drives identical deferred workloads — ``n_subs`` subscribers over several
 shape cohorts, half flushed early so every full flush drains TWO distinct
@@ -19,27 +19,40 @@ batches by the seed per-interest engine. Reported: flush seconds per round
 device (``Broker.device_passes``), and sharded/placed vs single speedups.
 Emits ``experiments/bench/BENCH_shard.json``.
 
-The forced host-device mesh requires ``XLA_FLAGS`` before jax initializes,
-so the measurement runs in a child process
-(``--xla_force_host_platform_device_count=8``); on a CPU host mesh the
-collectives are emulated and the sharded path's value is architectural
-(memory scale-out + the routing overhead trend), not raw speed — the
-recorded ratio quantifies exactly that overhead.
+The brokers run in this process over the first ``N_DEVICES`` of
+``jax.devices()``: one process holds every chip it drives. On the CPU the
+caller supplies virtual devices (``XLA_FLAGS=
+--xla_force_host_platform_device_count=4`` before the process starts); the
+collectives are then emulated and the recorded ratios measure routing
+overhead, not speed.
 
     PYTHONPATH=src python -m benchmarks.run --only shard
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-
-N_DEVICES = 8
-_MARK = "BENCH_SHARD_JSON:"
+N_DEVICES = 4
 
 
-def _child(scale: float, n_subs: int, n_rounds: int, per_round: int) -> None:
+def _mesh():
+    import jax
+
+    devices = jax.devices()
+    if len(devices) < N_DEVICES:
+        raise RuntimeError(
+            f"broker_shard needs {N_DEVICES} devices for its mesh, found "
+            f"{len(devices)} {devices[0].platform} device(s); on the CPU start "
+            f"the process with XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={N_DEVICES}"
+        )
+    return jax.make_mesh(
+        (N_DEVICES,), ("shard",),
+        axis_types=(jax.sharding.AxisType.Auto,),
+        devices=devices[:N_DEVICES],
+    )
+
+
+def run(scale: float = 1.0, n_subs: int = 12, n_rounds: int = 4,
+        per_round: int = 3) -> str:
     from repro.core import (
         Broker,
         CohortPlacement,
@@ -47,9 +60,7 @@ def _child(scale: float, n_subs: int, n_rounds: int, per_round: int) -> None:
         IrapEngine,
         PushPolicy,
     )
-    from repro.core.distributed import make_mesh_compat
-
-    from benchmarks.broker_flush import (
+    from .broker_flush import (
         _assert_outputs_equal,
         _caps,
         _composed,
@@ -57,7 +68,9 @@ def _child(scale: float, n_subs: int, n_rounds: int, per_round: int) -> None:
         _stream,
     )
 
-    mesh = make_mesh_compat((N_DEVICES,), ("shard",))
+    from .common import csv_row, save_json
+
+    mesh = _mesh()
 
     def build(name: str):
         d = Dictionary()
@@ -147,45 +160,6 @@ def _child(scale: float, n_subs: int, n_rounds: int, per_round: int) -> None:
         },
         "scale": scale,
     }
-    print(_MARK + json.dumps(payload), flush=True)
-
-
-def run(scale: float = 1.0, n_subs: int = 12, n_rounds: int = 4,
-        per_round: int = 3) -> str:
-    from .common import csv_row, save_json
-
-    env = dict(os.environ)
-    # overwrite rather than append: with repeated flags XLA honors the last
-    # occurrence, so an inherited --xla_force_host_platform_device_count
-    # (e.g. the CI mesh-test step's =4) would override the 8-device mesh
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={N_DEVICES}"
-    )
-    src = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
-    env["PYTHONPATH"] = (
-        src + os.pathsep
-        + os.path.dirname(os.path.dirname(__file__))
-        + os.pathsep + env.get("PYTHONPATH", "")
-    )
-    proc = subprocess.run(
-        [
-            sys.executable, "-m", "benchmarks.broker_shard", "--child",
-            str(scale), str(n_subs), str(n_rounds), str(per_round),
-        ],
-        capture_output=True,
-        text=True,
-        env=env,
-        timeout=3600,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"broker_shard child failed:\n{proc.stdout[-2000:]}"
-            f"\n{proc.stderr[-2000:]}"
-        )
-    line = next(
-        ln for ln in proc.stdout.splitlines() if ln.startswith(_MARK)
-    )
-    payload = json.loads(line[len(_MARK):])
     save_json("BENCH_shard", payload)
     us = payload["sharded"]["flush_eval_s_per_round"] * 1e6
     return csv_row(
@@ -198,10 +172,4 @@ def run(scale: float = 1.0, n_subs: int = 12, n_rounds: int = 4,
 
 
 if __name__ == "__main__":
-    if len(sys.argv) > 1 and sys.argv[1] == "--child":
-        _child(
-            float(sys.argv[2]), int(sys.argv[3]),
-            int(sys.argv[4]), int(sys.argv[5]),
-        )
-    else:
-        print(run())
+    print(run())

@@ -53,27 +53,3 @@ def run_triple_match(n=1 << 18, n_pat=8) -> str:
         f"GB/s={gbs:.2f};n={n};pats={n_pat};interpret_ok={ok}",
     )
 
-
-def run_merge_probe(s=1 << 16, q=1 << 15) -> str:
-    rng = np.random.default_rng(1)
-    store_rows = np.unique(
-        rng.integers(0, 1 << 18, size=(s, 3)).astype(np.int32), axis=0
-    )
-    pad = np.full((s - store_rows.shape[0], 3), np.iinfo(np.int32).max, np.int32)
-    store = jnp.asarray(np.concatenate([store_rows, pad]))
-    queries = jnp.asarray(rng.integers(0, 1 << 18, size=(q, 3)), jnp.int32)
-    f = jax.jit(lambda st, qq: ref.merge_probe_ref(st, qq))
-    dt = _time(f, store, queries)
-    i_k, f_k = ops.merge_probe(store[: 1 << 13], queries[:4096], use_kernel=True)
-    i_r, f_r = ref.merge_probe_ref(store[: 1 << 13], queries[:4096])
-    ok = bool(jnp.all(i_k == i_r) & jnp.all(f_k == f_r))
-    mps = q / dt / 1e6
-    save_json(
-        "kernel_merge_probe",
-        {"store": s, "queries": q, "s_per_call": dt,
-         "Mprobe_per_s_xla_cpu": mps, "interpret_matches_ref": ok},
-    )
-    return csv_row(
-        "kernel_merge_probe", dt * 1e6,
-        f"Mprobe/s={mps:.2f};store={s};q={q};interpret_ok={ok}",
-    )

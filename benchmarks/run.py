@@ -20,6 +20,10 @@ def main() -> None:
     ap.add_argument("--only", default="")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from . import broker_churn, broker_fanout, broker_flush
     from . import broker_journal, broker_scaling, broker_shard
     from . import fig4_growth, kernels_micro
@@ -32,7 +36,6 @@ def main() -> None:
         "table3_location": lambda: t23.run_location(args.days, args.per_day, args.scale),
         "fig4_growth": lambda: fig4_growth.run(args.days, args.per_day, args.scale),
         "kernel_triple_match": kernels_micro.run_triple_match,
-        "kernel_merge_probe": kernels_micro.run_merge_probe,
         "broker_scaling": lambda: broker_scaling.run(args.scale),
         "broker_churn": lambda: broker_churn.run(args.scale),
         "broker_flush": lambda: broker_flush.run(args.scale),

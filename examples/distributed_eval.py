@@ -24,10 +24,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import FOOTBALL, default_generator
+from repro.compile_cache import enable_compile_cache
 from repro.core.distributed import (
     gather_result_sets,
     make_distributed_evaluator,
-    make_mesh_compat,
     partition_rows,
     prepare_target_shards,
 )
@@ -35,8 +35,11 @@ from repro.core.interest import compile_interest
 
 
 def main():
+    enable_compile_cache()
     n_shards = 8
-    mesh = make_mesh_compat((n_shards,), ("data",))
+    mesh = jax.make_mesh(
+        (n_shards,), ("data",), axis_types=(jax.sharding.AxisType.Auto,)
+    )
     gen = default_generator(seed=5, scale=0.5)
     gen.initial_dump()
     tau_rows = gen.slice_for(

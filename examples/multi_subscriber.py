@@ -17,6 +17,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import Broker, InterestExpr, PushPolicy, StepCapacities
 
 from benchmarks.common import FOOTBALL, default_generator, football_caps
@@ -34,6 +35,7 @@ def class_interest(i: int) -> InterestExpr:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--days", type=int, default=3)
     ap.add_argument("--per-day", type=int, default=3)

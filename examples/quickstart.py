@@ -4,6 +4,7 @@
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (
     Dictionary,
     InterestExpr,
@@ -22,6 +23,7 @@ def show(d, title, store_or_out):
 
 
 def main():
+    enable_compile_cache()
     d = Dictionary()
     # Example 2: interest in athletes with goals, optionally a homepage
     expr = InterestExpr.parse(

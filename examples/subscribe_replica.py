@@ -13,6 +13,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import IrapEngine
 
 from benchmarks.common import (
@@ -25,6 +26,7 @@ from benchmarks.common import (
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--days", type=int, default=3)
     ap.add_argument("--per-day", type=int, default=3)

@@ -21,6 +21,7 @@ import jax
 import numpy as np
 
 from benchmarks.common import FOOTBALL, default_generator, football_caps
+from repro.compile_cache import enable_compile_cache
 from repro.core import IrapEngine
 from repro.data import ReplicaTokenPipeline, Verbalizer
 from repro.launch.steps import make_train_step
@@ -31,6 +32,7 @@ from repro.runtime import Trainer, TrainerConfig
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=60)
     ap.add_argument("--width", type=int, default=256)

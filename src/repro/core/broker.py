@@ -210,7 +210,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..kernels import ops as kops
 from .dictionary import Dictionary
@@ -219,7 +219,6 @@ from .distributed import (
     make_or_reduce,
     make_routed_probe_batched,
     prepare_target_shards,
-    shard_map_compat,
 )
 from .evaluation import (
     SideResult,
@@ -255,6 +254,7 @@ from .triples import (
     empty,
     from_array,
     rehome,
+    store_from_np,
     to_numpy,
     union,
 )
@@ -816,9 +816,10 @@ def make_sharded_cohort_step(
     )
     rep = P()
     if delta:
-        sharded_passes = shard_map_compat(
+        sharded_passes = jax.shard_map(
             shard_body_delta,
-            mesh,
+            mesh=mesh,
+            check_vma=False,
             in_specs=(
                 rep, rep, rep, rep, rep,
                 P(None, axis), P(None, axis),
@@ -827,15 +828,33 @@ def make_sharded_cohort_step(
             out_specs=(side_spec, side_spec),
         )
     else:
-        sharded_passes = shard_map_compat(
+        sharded_passes = jax.shard_map(
             shard_body,
-            mesh,
+            mesh=mesh,
+            check_vma=False,
             in_specs=(
                 rep, rep, rep, rep,
                 P(None, axis), P(None, axis),
                 rep, rep, rep, rep, rep, rep,
             ),
             out_specs=(side_spec, side_spec),
+        )
+
+    # every operand and result has one fixed sharding — τ partitions split
+    # over the mesh, everything else replicated — so the broker's cached
+    # AOT executable is always called with the shardings it was lowered
+    # for, whether an operand is fresh host data or a previous fire's τ/ρ
+    replicated = NamedSharding(mesh, P())
+    tau_parts = NamedSharding(mesh, P(None, axis))
+
+    def sharded_jit(fn, n_args: int, parts_at: Tuple[int, int]):
+        return jax.jit(
+            fn,
+            in_shardings=tuple(
+                tau_parts if i in parts_at else replicated
+                for i in range(n_args)
+            ),
+            out_shardings=replicated,
         )
 
     def merge_side(res: SideResult, out_cap: int, pull_cap: int) -> SideResult:
@@ -855,7 +874,6 @@ def make_sharded_cohort_step(
 
     if delta:
 
-        @jax.jit
         def step_delta(
             d_union: TripleStore,
             d_seg: jax.Array,
@@ -898,9 +916,8 @@ def make_sharded_cohort_step(
                 tuple(tree_index(out, i) for i in range(nc)),
             )
 
-        return step_delta
+        return sharded_jit(step_delta, 13, (5, 6))
 
-    @jax.jit
     def step(
         d_sets: Tuple[TripleStore, ...],
         a_sets: Tuple[TripleStore, ...],
@@ -941,7 +958,7 @@ def make_sharded_cohort_step(
             tuple(tree_index(out, i) for i in range(nc)),
         )
 
-    return step
+    return sharded_jit(step, 12, (4, 5))
 
 
 def _assemble_cohort_statics(
@@ -1017,8 +1034,9 @@ def _empty_outputs(caps: StepCapacities) -> EvalOutputs:
     """Canonical all-empty :class:`EvalOutputs` at one capacity family.
 
     The broker's empty-batch fast path returns this for a fired frontier
-    whose composed changeset has zero rows on both sides — nothing was
-    added or removed, so nothing propagates and no executable runs. Store
+    whose composed changeset has zero rows on both sides and whose
+    subscribers hold empty potential sets ρ — nothing was added, removed
+    or pending, so nothing propagates and no executable runs. Store
     capacities match what the full evaluation would produce (``r``/``r_i``
     at ``n_removed``, ``r'`` at ``pulls``, ``a`` at ``n_i + pulls``,
     ``a_i`` at ``n_i``), so downstream consumers see identical shapes.
@@ -1213,10 +1231,8 @@ class BrokerSubscription:
         """Load the initial RDFSlice-style subset into τ. True if caps grew."""
         grew = False
         while True:
-            store, overflow = from_array(
-                jnp.asarray(triples, jnp.int32), self.caps.tau
-            )
-            if not bool(overflow):
+            store, overflow = store_from_np(triples, self.caps.tau)
+            if not overflow:
                 self.tau = store
                 self.tau_version += 1
                 return grew
@@ -1752,23 +1768,15 @@ class Broker:
         """Fetch-or-compile one executable; compile time goes to rejit_s.
 
         On a miss the step is AOT-lowered against the concrete ``args`` so
-        the recorded time is pure compilation (evaluation stays outside);
-        if ahead-of-time compilation is unavailable the jitted callable is
-        cached instead and its first call pays the compile inline.
+        the recorded time is pure compilation (evaluation stays outside).
+        A lowering or compile error raises here, where it happens.
         """
         fn = self._exec_cache.get(key)
         if fn is not None:
             self._exec_cache.move_to_end(key)
             return fn
         t0 = time.perf_counter()
-        jitted = builder()
-        try:
-            fn = jitted.lower(*args).compile()
-        except (AttributeError, NotImplementedError):
-            # AOT lowering unavailable on this jax/backend only — genuine
-            # compile errors must propagate. The fallback's first call pays
-            # its compile inline (inflating elapsed_s, not rejit_s).
-            fn = jitted
+        fn = builder().lower(*args).compile()
         self._exec_cache[key] = fn
         while len(self._exec_cache) > self.exec_cache_max:
             self._exec_cache.popitem(last=False)
@@ -1945,16 +1953,21 @@ class Broker:
 
         ordered = sorted(groups, key=group_order)
         # empty-batch fast path: a composed batch with zero rows on both
-        # sides delivers nothing — skip statics, executables, and passes
+        # sides, fired for subscribers whose potential sets ρ are empty,
+        # delivers nothing — skip statics, executables, and passes
         # entirely and hand its subscribers canonical empty outputs (their
         # τ/ρ are untouched; consuming the batch is composition-neutral,
-        # <∅, ∅> composed with any future changeset is that changeset)
+        # <∅, ∅> composed with any future changeset is that changeset).
+        # A non-empty ρ takes the full pass: the seed engine re-reports
+        # ρ's potential rows as a_i even for an empty changeset.
         outs: Dict[int, EvalOutputs] = {}
         fronts = []
         for since in ordered:
             batch = self._batches[since]
             d_rows, a_rows = batch.row_bounds()
-            if d_rows == 0 and a_rows == 0:
+            if d_rows == 0 and a_rows == 0 and all(
+                int(self.subs[k].rho.n) == 0 for k in groups[since]
+            ):
                 for k in groups[since]:
                     outs[k] = _empty_outputs(self.subs[k].caps)
                 continue

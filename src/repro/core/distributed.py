@@ -63,43 +63,6 @@ from .interest import CompiledInterest
 from .triples import PAD, TripleStore, from_array, lex_sort
 
 
-def make_mesh_compat(shape: Tuple[int, ...], axis_names: Tuple[str, ...]):
-    """``jax.make_mesh`` with explicit Auto axis types where supported.
-
-    Pre-``AxisType`` jax (< 0.5) takes no ``axis_types`` argument; newer jax
-    wants the axes marked Auto so the collectives here stay legal. One home
-    for the version shim, shared by the examples and the subprocess tests.
-    """
-    try:
-        from jax.sharding import AxisType
-
-        return jax.make_mesh(
-            shape, axis_names, axis_types=(AxisType.Auto,) * len(axis_names)
-        )
-    except (ImportError, TypeError):
-        return jax.make_mesh(shape, axis_names)
-
-
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions, replication checking off.
-
-    Binary-search carries and the masked-ownership dataflow mix varying and
-    unvarying axes, so replication checking is disabled (``check_vma`` on
-    current jax; ``check_rep`` pre-0.5).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-
-
 # ---------------------------------------------------------------------------
 # host-side partitioning
 # ---------------------------------------------------------------------------
@@ -430,8 +393,9 @@ def make_distributed_evaluator(
         pulls=TripleStore(spo=P(axis, None, None), n=P(axis)),
         overflow=P(axis),
     )
-    mapped = shard_map_compat(
-        shard_fn, mesh, in_specs=(spec, spec, spec), out_specs=out_specs
+    mapped = jax.shard_map(
+        shard_fn, mesh=mesh, in_specs=(spec, spec, spec),
+        out_specs=out_specs, check_vma=False,
     )
     return jax.jit(mapped)
 

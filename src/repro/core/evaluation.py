@@ -36,6 +36,7 @@ from .triples import (
     from_array,
     lex_sort,
     prefix_range,
+    select,
 )
 
 
@@ -254,11 +255,7 @@ def make_side_evaluator(
         first = jnp.concatenate(
             [jnp.ones((1,), bool), s[1:] != s[:-1]]
         ) & (s != PAD)
-        order = jnp.argsort(jnp.logical_not(first), stable=True)
-        uniq = s[order]
-        count = jnp.sum(first)
-        idx = jnp.arange(s.shape[0], dtype=jnp.int32)
-        uniq = jnp.where(idx < count, uniq, PAD)
+        uniq, count = compact(s, first)
         return uniq[:dedup_cap], count > dedup_cap
     R = id_capacity
     K = fanout
@@ -502,10 +499,8 @@ def make_side_evaluator(
             pr = jnp.full((1, 3), PAD, jnp.int32)
         pulls, ovf_p = from_array(pr, pull_capacity)
 
-        inter_rows = jnp.where(inter[:, None], spo, PAD)
-        pot_rows = jnp.where(potential[:, None], spo, PAD)
-        inter_store, ovf_i = from_array(inter_rows, out_capacity)
-        pot_store, ovf_q = from_array(pot_rows, out_capacity)
+        inter_store, ovf_i = select(m, inter, out_capacity)
+        pot_store, ovf_q = select(m, potential, out_capacity)
 
         return SideResult(
             interesting=inter_store,

@@ -41,6 +41,7 @@ from .triples import (
     from_array,
     member,
     rehome,
+    store_from_np,
     to_numpy,
     union,
 )
@@ -137,6 +138,7 @@ def combine_side_results(
     return tau1, rho1, out
 
 
+@partial(jax.jit, static_argnames=("capacity",))
 def compose_changesets(
     d1: TripleStore,
     a1: TripleStore,
@@ -335,13 +337,9 @@ class ChangesetBatch:
 
     def _materialize(self) -> None:
         while True:
-            d, ovf_d = from_array(
-                jnp.asarray(self.removed_np, jnp.int32), self.capacity
-            )
-            a, ovf_a = from_array(
-                jnp.asarray(self.added_np, jnp.int32), self.capacity
-            )
-            if not bool(ovf_d | ovf_a):
+            d, ovf_d = store_from_np(self.removed_np, self.capacity)
+            a, ovf_a = store_from_np(self.added_np, self.capacity)
+            if not (ovf_d or ovf_a):
                 self.removed, self.added = d, a
                 self.d_rows = self.a_rows = None
                 return
@@ -358,8 +356,8 @@ class ChangesetBatch:
         while self.capacity < need:
             self.capacity *= 2
             self.grow_count += 1
-        d2, _ = from_array(jnp.asarray(removed, jnp.int32), self.capacity)
-        a2, _ = from_array(jnp.asarray(added, jnp.int32), self.capacity)
+        d2, _ = store_from_np(removed, self.capacity)
+        a2, _ = store_from_np(added, self.capacity)
         while True:
             d, a, overflow = compose_changesets(
                 self.removed, self.added, d2, a2, self.capacity

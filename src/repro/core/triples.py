@@ -80,13 +80,24 @@ def _dedup_sorted_mask(spo: jax.Array) -> jax.Array:
 
 
 def compact(spo: jax.Array, keep: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Stable-partition kept rows to the front; pad the rest. Returns (rows, count)."""
-    order = jnp.argsort(jnp.logical_not(keep), stable=True)
-    rows = spo[order]
-    count = jnp.sum(keep, dtype=jnp.int32)
-    idx = jnp.arange(spo.shape[0], dtype=jnp.int32)
-    rows = jnp.where((idx < count)[:, None], rows, jnp.full_like(rows, PAD))
-    return rows, count
+    """Stable-partition kept rows to the front; pad the rest. Returns (rows, count).
+
+    Works on the leading axis of any array. Output slot ``i`` takes the
+    ``(i + 1)``-th kept row, found by binary search over the running count
+    of kept rows: no sort, because on the TPU a sort this size takes about
+    ten times longer to compile than the search (and the broker compiles
+    one program per cohort shape).
+    """
+    n = spo.shape[0]
+    if n == 0:
+        return spo, jnp.zeros((), jnp.int32)
+    kept = jnp.cumsum(keep, dtype=jnp.int32)
+    count = kept[-1]
+    idx = jnp.arange(n, dtype=jnp.int32)
+    src = jnp.searchsorted(kept, idx + 1, side="left").astype(jnp.int32)
+    rows = jnp.take(spo, jnp.minimum(src, n - 1), axis=0)
+    live = (idx < count).reshape((n,) + (1,) * (spo.ndim - 1))
+    return jnp.where(live, rows, PAD), count
 
 
 def from_array(spo: jax.Array, capacity: int) -> Tuple[TripleStore, jax.Array]:
@@ -99,8 +110,26 @@ def from_array(spo: jax.Array, capacity: int) -> Tuple[TripleStore, jax.Array]:
     if spo.ndim != 2 or spo.shape[1] != 3:
         raise ValueError(f"expected (N, 3) triples, got {spo.shape}")
     srt = lex_sort(spo)
-    keep = _dedup_sorted_mask(srt)
-    rows, count = compact(srt, keep)
+    rows, count = compact(srt, _dedup_sorted_mask(srt))
+    return _fit(rows, count, capacity)
+
+
+def select(
+    store: TripleStore, keep: jax.Array, capacity: int
+) -> Tuple[TripleStore, jax.Array]:
+    """The kept rows of a store as a store of ``capacity``: (store, overflowed).
+
+    Equal to :func:`from_array` of the kept rows, without its sort: a
+    store's rows are already sorted and distinct.
+    """
+    rows, count = compact(store.spo, keep & store.valid_mask())
+    return _fit(rows, count, capacity)
+
+
+def _fit(
+    rows: jax.Array, count: jax.Array, capacity: int
+) -> Tuple[TripleStore, jax.Array]:
+    """Compacted sorted rows padded or cut to ``capacity`` rows."""
     c = rows.shape[0]
     if c < capacity:
         rows = jnp.concatenate(
@@ -112,11 +141,33 @@ def from_array(spo: jax.Array, capacity: int) -> Tuple[TripleStore, jax.Array]:
     return TripleStore(spo=rows, n=jnp.minimum(count, capacity)), overflow
 
 
+def store_from_np(
+    triples: np.ndarray, capacity: int
+) -> Tuple[TripleStore, bool]:
+    """:func:`from_array` for rows held on the host: (store, overflowed).
+
+    The rows are sorted and deduplicated on the host, so nothing is sorted
+    on the device: on the TPU a sort compiles for seconds to minutes per
+    shape, while replicas and incoming changesets arrive as host arrays.
+    """
+    rows = np.asarray(triples, np.int32).reshape(-1, 3)
+    rows = rows[np.lexsort((rows[:, 2], rows[:, 1], rows[:, 0]))]
+    first = np.ones(rows.shape[0], bool)
+    first[1:] = (rows[1:] != rows[:-1]).any(axis=1)
+    rows = rows[first & (rows[:, 0] != PAD)]
+    count = min(rows.shape[0], capacity)
+    out = np.full((capacity, 3), PAD, np.int32)
+    out[:count] = rows[:count]
+    store = TripleStore(spo=jnp.asarray(out), n=jnp.asarray(count, jnp.int32))
+    return store, rows.shape[0] > capacity
+
+
 def from_numpy(triples: np.ndarray, capacity: int) -> TripleStore:
-    store, overflow = from_array(jnp.asarray(triples, dtype=jnp.int32), capacity)
-    if bool(overflow):
+    store, overflow = store_from_np(triples, capacity)
+    if overflow:
         raise ValueError(
-            f"{triples.shape[0]} distinct triples exceed capacity {capacity}"
+            f"{np.asarray(triples).shape[0]} distinct triples exceed "
+            f"capacity {capacity}"
         )
     return store
 
@@ -193,22 +244,50 @@ def intersection(a: TripleStore, b: TripleStore) -> TripleStore:
     return TripleStore(spo=rows, n=count)
 
 
+def merge_sorted(a: jax.Array, b: jax.Array) -> jax.Array:
+    """Two lex-sorted row arrays merged into one lex-sorted array.
+
+    Output row ``k`` is found by a co-rank binary search (merge path): the
+    number ``i`` of ``a`` rows among the first ``k`` outputs is the least
+    ``i`` with ``b[k - i - 1] < a[i]``. Gathers and compares only: equal to
+    ``lex_sort(concatenate([a, b]))``, with no sort to compile (on the TPU
+    a sort of a replica's size takes minutes to compile).
+    """
+    na, nb = a.shape[0], b.shape[0]
+    if na == 0 or nb == 0:
+        return b if na == 0 else a
+    k = jnp.arange(na + nb, dtype=jnp.int32)
+    lo = jnp.maximum(0, k - nb)
+    hi = jnp.minimum(k, na)
+    iters = max(1, int(np.ceil(np.log2(min(na, nb) + 1))) + 1)
+
+    def body(_, state):
+        lo, hi = state
+        mid = (lo + hi) // 2
+        j = k - mid
+        a_mid = jnp.take(a, jnp.minimum(mid, na - 1), axis=0)
+        b_prev = jnp.take(b, jnp.clip(j - 1, 0, nb - 1), axis=0)
+        ok = (mid >= na) | (j <= 0) | lex_less(b_prev, a_mid)
+        active = lo < hi
+        return (
+            jnp.where(active & ~ok, mid + 1, lo),
+            jnp.where(active & ok, mid, hi),
+        )
+
+    i, _ = jax.lax.fori_loop(0, iters, body, (lo, hi))
+    j = k - i
+    a_i = jnp.take(a, jnp.minimum(i, na - 1), axis=0)
+    b_j = jnp.take(b, jnp.minimum(j, nb - 1), axis=0)
+    take_a = (j >= nb) | ((i < na) & ~lex_less(b_j, a_i))
+    return jnp.where(take_a[:, None], a_i, b_j)
+
+
 def union(a: TripleStore, b: TripleStore, capacity: int | None = None) -> Tuple[TripleStore, jax.Array]:
     """a ∪ b with the given output capacity (defaults to a's). Returns (store, overflowed)."""
     capacity = a.capacity if capacity is None else capacity
-    both = jnp.concatenate([a.spo, b.spo], axis=0)
-    srt = lex_sort(both)
-    keep = _dedup_sorted_mask(srt)
-    rows, count = compact(srt, keep)
-    overflow = count > capacity
-    if rows.shape[0] < capacity:
-        rows = jnp.concatenate(
-            [rows, jnp.full((capacity - rows.shape[0], 3), PAD, dtype=jnp.int32)],
-            axis=0,
-        )
-    else:
-        rows = rows[:capacity]
-    return TripleStore(spo=rows, n=jnp.minimum(count, capacity)), overflow
+    srt = merge_sorted(a.spo, b.spo)
+    rows, count = compact(srt, _dedup_sorted_mask(srt))
+    return _fit(rows, count, capacity)
 
 
 def apply_changeset(store: TripleStore, removed: TripleStore, added: TripleStore) -> Tuple[TripleStore, jax.Array]:

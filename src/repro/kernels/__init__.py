@@ -2,6 +2,5 @@
 
 Kernels (each: <name>.py kernel + ops.py wrapper + ref.py oracle):
   * triple_match — fused multi-pattern triple matching (uint32 bitset emit)
-  * merge_join   — blocked sort-merge membership probe (candidate assertion)
 """
-from . import merge_join, ops, ref, triple_match  # noqa: F401
+from . import ops, ref, triple_match  # noqa: F401

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import merge_join, ref, triple_match
+from . import ref, triple_match
 
 PAD = ref.PAD
 FORCE_KERNEL = False
@@ -298,75 +298,3 @@ def lane_bits_batched(
         out = jnp.where(row_mask, out, jnp.uint32(0))
     return out
 
-
-def merge_probe(
-    store: jax.Array,
-    queries: jax.Array,
-    *,
-    use_kernel: bool | None = None,
-    windowed: bool = False,
-):
-    """(idx, found) of each query row in a lex-sorted store (original order).
-
-    ``store``: int32[S, 3] lex-sorted with PAD tail. ``queries``: int32[Q, 3]
-    any order. ``found`` is bool[Q]; ``idx`` is the searchsorted-left position.
-
-    The kernel path requires every sorted-query block's covering store window
-    to fit STORE_BLOCK rows; when that precondition fails (measured host-side
-    in eager mode) the call transparently falls back to the XLA path.
-    """
-    if not _want_kernel(use_kernel):
-        return ref.merge_probe_ref(store, queries)
-
-    qb, sb = merge_join.QUERY_BLOCK, merge_join.STORE_BLOCK
-    q = queries.shape[0]
-    s = store.shape[0]
-
-    # sort queries, pad to block multiples
-    perm = jnp.lexsort((queries[:, 2], queries[:, 1], queries[:, 0]))
-    qs = queries[perm]
-    q_pad = -q % qb
-    if q_pad:
-        qs = jnp.concatenate([qs, jnp.full((q_pad, 3), PAD, jnp.int32)], axis=0)
-    s_pad = -s % sb
-    store_p = store
-    if s_pad:
-        store_p = jnp.concatenate(
-            [store, jnp.full((s_pad, 3), PAD, jnp.int32)], axis=0
-        )
-    sp_len = store_p.shape[0]
-    g = qs.shape[0] // qb
-
-    # covering window per query block: position of its first/last query
-    firsts = qs[0::qb]
-    lasts = qs[qb - 1 :: qb]
-    start, _ = ref.merge_probe_ref(store_p, firsts)
-    end, _ = ref.merge_probe_ref(store_p, lasts)
-    end = jnp.minimum(end + 1, sp_len)
-    win_blk = start // sb
-    fits = jnp.all(end <= (win_blk + 1) * sb)
-
-    if not jax.core.is_concrete(fits):
-        # inside a jit trace we cannot branch on the skew check
-        return ref.merge_probe_ref(store, queries)
-    if not bool(fits) or sp_len < sb:
-        return ref.merge_probe_ref(store, queries)
-
-    if windowed:
-        idx_s, found_s = merge_join.merge_probe_windowed(
-            store_p, win_blk.astype(jnp.int32), qs, interpret=not _on_tpu()
-        )
-    else:
-        starts = (win_blk * sb).astype(jnp.int32)
-        gather = jax.vmap(
-            lambda st: jax.lax.dynamic_slice(store_p, (st, 0), (sb, 3))
-        )
-        windows = gather(starts)
-        idx_s, found_s = merge_join.merge_probe_pallas(
-            windows, starts, qs, interpret=not _on_tpu()
-        )
-
-    idx_s = idx_s[:q]
-    found_s = found_s[:q].astype(bool)
-    inv = jnp.zeros_like(perm).at[perm].set(jnp.arange(q))
-    return idx_s[inv], found_s[inv]

@@ -155,40 +155,3 @@ def lane_refine_ref(
         dtype=jnp.uint32,
     )
 
-
-def _lex_less(a: jax.Array, b: jax.Array) -> jax.Array:
-    s_lt = a[..., 0] < b[..., 0]
-    s_eq = a[..., 0] == b[..., 0]
-    p_lt = a[..., 1] < b[..., 1]
-    p_eq = a[..., 1] == b[..., 1]
-    o_lt = a[..., 2] < b[..., 2]
-    return s_lt | (s_eq & (p_lt | (p_eq & o_lt)))
-
-
-def merge_probe_ref(store: jax.Array, queries: jax.Array):
-    """Lexicographic searchsorted-left + membership of queries in a sorted store.
-
-    Returns (idx int32[Q], found bool[Q]). ``store``: int32[S, 3] lex-sorted
-    with PAD tail; ``queries``: int32[Q, 3] (any order).
-    """
-    c = store.shape[0]
-    q = queries.shape[0]
-    lo = jnp.zeros((q,), dtype=jnp.int32)
-    hi = jnp.full((q,), c, dtype=jnp.int32)
-    iters = max(1, int(np.ceil(np.log2(c + 1))) + 1)
-
-    def body(_, state):
-        lo, hi = state
-        mid = (lo + hi) // 2
-        row = jnp.take(store, jnp.minimum(mid, c - 1), axis=0)
-        go_right = _lex_less(row, queries)
-        active = lo < hi
-        return (
-            jnp.where(active & go_right, mid + 1, lo),
-            jnp.where(active & ~go_right, mid, hi),
-        )
-
-    lo, _ = jax.lax.fori_loop(0, iters, body, (lo, hi))
-    rows = jnp.take(store, jnp.minimum(lo, c - 1), axis=0)
-    found = (lo < c) & jnp.all(rows == queries, axis=-1)
-    return lo, found

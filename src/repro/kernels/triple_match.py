@@ -44,8 +44,15 @@ The W bank words of the multi-word block live in vector registers between
 the compare loop and the store/route step — VMEM holds only the triple tile
 and the final output block, so footprint grows with W only through the
 (tiny, replicated) ``(32 W, 3)`` pattern operand and the word-kernel output
-block. The lane-routing kernel additionally replicates the ``(R, nt)`` lane
-map and the ``(R, 1)`` member mask, reading one row per member grid step.
+block. The lane-routing kernel additionally keeps the ``(R, nt)`` lane map
+and the ``(R, 1)`` member mask whole in SMEM, reading one row per member
+grid step as scalars. Its grid's first axis is the member axis, so the
+kernel must not run under ``jax.vmap`` (which would prepend a batch axis to
+the grid); the broker calls it on the explicit member-stacked cohort.
+
+Every kernel takes ``interpret`` as a required keyword: the ops wrappers
+pass ``interpret=not on_tpu``, so no caller can run the interpreter on the
+chip by omission.
 """
 from __future__ import annotations
 
@@ -55,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 PAD = np.int32(np.iinfo(np.int32).max)
 WILDCARD = np.int32(-1)
@@ -135,27 +143,30 @@ def _kernel_lanes(
 ):
     """Fused bank emit + lane routing + member mask for ONE cohort member.
 
-    The member's lane map row arrives as a (1, n_tgt) block; bank words stay
-    in registers and each local pattern bit is selected out of its word via
-    a static unroll over the W words (lane values are traced, so the word
-    choice is a select chain, not a dynamic index).
+    The whole ``(R, n_tgt)`` lane map and ``(R, 1)`` member mask sit in
+    SMEM and are read as scalars at row ``program_id(0)`` (the member grid
+    axis; Mosaic refuses them as (1, n_tgt) / (1, 1) VMEM blocks). Bank
+    words stay in registers and each local pattern bit is selected out of
+    its word via a static unroll over the W words (lane values are traced,
+    so the word choice is a select chain, not a dynamic index).
     """
+    k = pl.program_id(0)
     accs = _match_words(pat_ref, s_ref[0], p_ref[0], o_ref[0], n_pat)
     local = jnp.zeros(s_ref[0].shape, dtype=jnp.uint32)
     for t in range(n_tgt):
-        lane = lanes_ref[0, t]
+        lane = lanes_ref[k, t]
         wi = lane // 32
         sh = (lane % 32).astype(jnp.uint32)
         word = accs[0]
         for w in range(1, len(accs)):
             word = jnp.where(wi == w, accs[w], word)
         local = local | (((word >> sh) & jnp.uint32(1)) << jnp.uint32(t))
-    active = act_ref[0, 0] != 0
+    active = act_ref[k, 0] != 0
     out_ref[0] = jnp.where(active, local, jnp.zeros_like(local))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def triple_match_pallas(spo: jax.Array, patterns: jax.Array, *, interpret: bool = True) -> jax.Array:
+def triple_match_pallas(spo: jax.Array, patterns: jax.Array, *, interpret: bool) -> jax.Array:
     """uint32[N] pattern bitset for lex-agnostic (N, 3) int32 triples.
 
     N must be a multiple of 128 * BLOCK_ROWS (the ops wrapper pads).
@@ -185,7 +196,7 @@ def triple_match_pallas(spo: jax.Array, patterns: jax.Array, *, interpret: bool 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def triple_match_words_pallas(
-    spo: jax.Array, patterns: jax.Array, *, interpret: bool = True
+    spo: jax.Array, patterns: jax.Array, *, interpret: bool
 ) -> jax.Array:
     """uint32[W, N] multi-word bank bitset in one kernel invocation.
 
@@ -225,7 +236,7 @@ def triple_match_words_segmented_pallas(
     seg: jax.Array,
     *,
     n_seg: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """uint32[n_seg, W, N] segment-masked bank bitset in one invocation.
 
@@ -325,7 +336,7 @@ def lane_refine_pallas(
     parents: jax.Array,
     residual: jax.Array,
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """uint32[Wv, N] refined virtual-lane words from real-bank words.
 
@@ -376,7 +387,7 @@ def triple_match_lanes_pallas(
     lanes: jax.Array,
     active: jax.Array,
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """uint32[R, N] fused multi-word emit + lane routing for a cohort.
 
@@ -399,13 +410,12 @@ def triple_match_lanes_pallas(
     grid = (r, rows // BLOCK_ROWS)
     col_spec = pl.BlockSpec((1, BLOCK_ROWS, 128), lambda k, i: (k, i, 0))
     pat_spec = pl.BlockSpec((max(1, n_pat), 3), lambda k, i: (0, 0))
-    lane_spec = pl.BlockSpec((1, n_tgt), lambda k, i: (k, 0))
-    act_spec = pl.BlockSpec((1, 1), lambda k, i: (k, 0))
+    smem_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     out = pl.pallas_call(
         functools.partial(_kernel_lanes, n_pat=n_pat, n_tgt=n_tgt),
         grid=grid,
-        in_specs=[pat_spec, lane_spec, act_spec, col_spec, col_spec, col_spec],
+        in_specs=[pat_spec, smem_spec, smem_spec, col_spec, col_spec, col_spec],
         out_specs=col_spec,
         out_shape=jax.ShapeDtypeStruct((r, rows, 128), jnp.uint32),
         interpret=interpret,

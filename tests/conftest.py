@@ -1,12 +1,11 @@
 """Shared test configuration.
 
-``hypothesis`` is an *optional* dev dependency (see requirements-dev.txt):
-the property-test modules (test_kernels.py, test_properties.py,
-test_broker_properties.py) guard themselves with
-``pytest.importorskip("hypothesis")`` at import time, so without it they are
-reported as **skipped** instead of failing collection.
+``hypothesis`` is a required dev dependency (see requirements-dev.txt): the
+property tests (test_kernels.py, test_properties.py,
+test_broker_properties.py and the property cases of the broker test files)
+belong to the tier-1 suite.
 
-This conftest additionally puts ``src/`` on ``sys.path`` so
+This conftest puts ``src/`` on ``sys.path`` so
 ``python -m pytest`` works from the repo root even without
 ``PYTHONPATH=src``.
 """

@@ -180,9 +180,6 @@ def test_multi_frontier_flush_stacks_same_shape_cohorts():
 def test_device_resident_property_random_streams():
     """Hypothesis sweep: random policies + random streams stay bit-identical
     between the device-resident and round-trip paths, including flushes."""
-    pytest.importorskip(
-        "hypothesis", reason="optional dev dependency (requirements-dev.txt)"
-    )
     from hypothesis import HealthCheck, given, settings
     from hypothesis import strategies as st
 
@@ -339,9 +336,6 @@ def test_delta_chain_nonmonotone_property():
     """Hypothesis sweep over tiny-pool streams (heavy add/remove/re-add
     churn of the same triples across frontiers): delta-chain flushes stay
     bit-identical to the stacked pass, step by step and at flush."""
-    pytest.importorskip(
-        "hypothesis", reason="optional dev dependency (requirements-dev.txt)"
-    )
     from hypothesis import HealthCheck, given, settings
     from hypothesis import strategies as st
 

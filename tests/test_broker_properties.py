@@ -7,11 +7,6 @@ path (two uint32 words). Steps are compiled once per plan combination at
 module scope, so hypothesis examples only vary data.
 """
 import numpy as np
-import pytest
-
-pytest.importorskip(
-    "hypothesis", reason="optional dev dependency (requirements-dev.txt)"
-)
 import jax.numpy as jnp
 from hypothesis import HealthCheck, given, settings
 from hypothesis import strategies as st

@@ -305,9 +305,6 @@ def test_recovery_refuses_overcompacted_journal(tmp_path):
 def test_crash_boundary_property_random_schedules():
     """Hypothesis sweep: random cadences, random streams, crash at a random
     boundary — recovery always lands on the captured state."""
-    pytest.importorskip(
-        "hypothesis", reason="optional dev dependency (requirements-dev.txt)"
-    )
     import tempfile
     from pathlib import Path
 

@@ -68,8 +68,8 @@ GOLDEN_SCRIPT = textwrap.dedent(
 
     A = "rdf:type"
     CAPS = StepCapacities(n_removed=16, n_added=16, tau=64, rho=64, pulls=32)
-    from repro.core.distributed import make_mesh_compat
-    mesh = make_mesh_compat((8,), ("shard",))
+    mesh = jax.make_mesh(
+        (8,), ("shard",), axis_types=(jax.sharding.AxisType.Auto,))
 
     EXPRS = [
         InterestExpr.parse("g", "t0",
@@ -222,15 +222,14 @@ def _mesh_or_skip(n: int):
             f"needs a >= {n}-device host mesh "
             "(XLA_FLAGS=--xla_force_host_platform_device_count=4)"
         )
-    from repro.core.distributed import make_mesh_compat
-
-    return make_mesh_compat((n,), ("shard",))
+    return jax.make_mesh(
+        (n,), ("shard",), axis_types=(jax.sharding.AxisType.Auto,)
+    )
 
 
 @pytest.mark.slow
 def test_placement_and_churn_property():
     """Random placement policy + churn order == single-device, bit for bit."""
-    hyp = pytest.importorskip("hypothesis")
     from hypothesis import given, settings, strategies as st_mod
 
     mesh = _mesh_or_skip(4)
@@ -353,7 +352,7 @@ def test_or_reduce_words_reassembly():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.distributed import make_or_reduce, shard_map_compat
+    from repro.core.distributed import make_or_reduce
     from repro.core.triples import PAD
     from repro.kernels import ops as kops
 
@@ -380,8 +379,9 @@ def test_or_reduce_words_reassembly():
         return words[None], covered[None]
 
     fn = jax.jit(
-        shard_map_compat(
-            body, mesh, in_specs=(P(), P()), out_specs=(P("shard"), P("shard"))
+        jax.shard_map(
+            body, mesh=mesh, in_specs=(P(), P()),
+            out_specs=(P("shard"), P("shard")), check_vma=False,
         )
     )
     words_sh, covered_sh = fn(spo, bank)

@@ -24,7 +24,6 @@ SCRIPT = textwrap.dedent(
     from repro.core.distributed import (
         gather_result_sets,
         make_distributed_evaluator,
-        make_mesh_compat,
         partition_rows,
         prepare_target_shards,
     )
@@ -33,7 +32,9 @@ SCRIPT = textwrap.dedent(
     from repro.core.triples import PAD
 
     N_SHARDS = 4
-    mesh = make_mesh_compat((N_SHARDS,), ("data",))
+    mesh = jax.make_mesh(
+        (N_SHARDS,), ("data",), axis_types=(jax.sharding.AxisType.Auto,)
+    )
 
     d = Dictionary()
     for t in ([f"s{i}" for i in range(12)] + ["type", "p0", "p1", "goals",

@@ -9,9 +9,8 @@ chunked composition (per-32-lane :func:`ref.pattern_bitmask_ref` words +
 :func:`ops.lane_bits_batched` routing), including W = 1 banks,
 non-multiple-of-32 bank widths, and all-tombstone words.
 
-Deliberately hypothesis-free (seeded ``numpy.random``): these are tier-1
-kernel parity tests and must run in every CI configuration, including ones
-without the optional dev dependencies.
+Deliberately hypothesis-free (seeded ``numpy.random``): fixed shapes keep
+these tier-1 kernel parity tests cheap and reproducible.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -305,6 +304,27 @@ def test_lane_kernel_direct_tile_aligned():
     )
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert not np.asarray(got[1]).any()  # masked member: all zeros
+
+
+@pytest.mark.parametrize("r,n_active", [(4, 1), (8, 5)])
+def test_lane_kernel_smem_rows_per_member(r, n_active):
+    """The lane map and member mask are read from SMEM at the member grid
+    index: every member over several row blocks, a multi-word bank, and
+    trailing padding members (mask 0) all match the oracle."""
+    rng = np.random.default_rng(31 + r)
+    nt = 5
+    spo_b = jnp.asarray(
+        np.stack([_random_spo(rng, 2 * TILE) for _ in range(r)])
+    )
+    pats = jnp.asarray(_random_bank(rng, 70, tombstone_frac=0.1))
+    lanes = jnp.asarray(rng.integers(0, 70, size=(r, nt)).astype(np.int32))
+    active = np.arange(r) < n_active
+    act = jnp.asarray(active.astype(np.int32).reshape(r, 1))
+    got = triple_match_lanes_pallas(spo_b, pats, lanes, act, interpret=True)
+    want = ref.pattern_lane_bits_ref(spo_b, pats, lanes, jnp.asarray(active))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not np.asarray(got[n_active:]).any()  # padding members: zeros
+    assert np.asarray(got[:n_active]).any()
 
 
 def test_lane_kernel_active_none_means_all_active():

@@ -1,28 +1,16 @@
 """Pallas kernels (interpret mode) vs pure-jnp oracles: shape/dtype sweeps."""
 import jax.numpy as jnp
 import numpy as np
-import pytest
-
-pytest.importorskip(
-    "hypothesis", reason="optional dev dependency (requirements-dev.txt)"
-)
 from hypothesis import HealthCheck, given, settings
 from hypothesis import strategies as st
 
-from repro.kernels import merge_join, ops, ref
-from repro.kernels.triple_match import BLOCK_ROWS, triple_match_pallas
+from repro.kernels import ops, ref
+from repro.kernels.triple_match import BLOCK_ROWS
 
 HSETTINGS = settings(
     max_examples=25, deadline=None, suppress_health_check=[HealthCheck.too_slow]
 )
 PAD = ref.PAD
-
-
-def sorted_store(rows: np.ndarray, capacity: int) -> jnp.ndarray:
-    rows = np.unique(rows.astype(np.int32), axis=0) if rows.size else rows.reshape(0, 3)
-    out = np.full((capacity, 3), PAD, np.int32)
-    out[: rows.shape[0]] = rows[np.lexsort((rows[:, 2], rows[:, 1], rows[:, 0]))] if rows.size else rows
-    return jnp.asarray(out)
 
 
 # ---------------------------------------------------------------------------
@@ -65,82 +53,8 @@ def test_triple_match_wildcard_only_pattern():
     np.testing.assert_array_equal(np.asarray(got), [1, 1])
 
 
-# ---------------------------------------------------------------------------
-# merge_join
-# ---------------------------------------------------------------------------
-@given(
-    s_rows=st.integers(0, 400),
-    q_rows=st.integers(1, 3000),
-    vocab=st.integers(2, 25),
-    seed=st.integers(0, 2**31 - 1),
-)
-@HSETTINGS
-def test_merge_probe_matches_ref(s_rows, q_rows, vocab, seed):
-    rng = np.random.default_rng(seed)
-    store = sorted_store(
-        rng.integers(0, vocab, size=(s_rows, 3)), max(merge_join.STORE_BLOCK, 2048)
-    )
-    queries = jnp.asarray(
-        rng.integers(0, vocab, size=(q_rows, 3)).astype(np.int32)
-    )
-    i_ref, f_ref = ref.merge_probe_ref(store, queries)
-    i_k, f_k = ops.merge_probe(store, queries, use_kernel=True)
-    np.testing.assert_array_equal(np.asarray(f_k), np.asarray(f_ref))
-    np.testing.assert_array_equal(np.asarray(i_k), np.asarray(i_ref))
-
-
-def test_merge_probe_skew_falls_back():
-    """Store too large for one window -> transparent XLA fallback, still exact."""
-    rng = np.random.default_rng(1)
-    big = merge_join.STORE_BLOCK * 4
-    store = sorted_store(rng.integers(0, 2000, size=(big, 3)), big)
-    queries = jnp.asarray(rng.integers(0, 2000, size=(512, 3)).astype(np.int32))
-    i_ref, f_ref = ref.merge_probe_ref(store, queries)
-    i_k, f_k = ops.merge_probe(store, queries, use_kernel=True)
-    np.testing.assert_array_equal(np.asarray(f_k), np.asarray(f_ref))
-    np.testing.assert_array_equal(np.asarray(i_k), np.asarray(i_ref))
-
-
-def test_merge_probe_duplicates_and_pads():
-    store = sorted_store(np.asarray([[1, 1, 1], [1, 1, 1], [2, 2, 2]]), 2048)
-    queries = jnp.asarray(
-        [[1, 1, 1], [2, 2, 2], [3, 3, 3], [1, 1, 1]], jnp.int32
-    )
-    i_k, f_k = ops.merge_probe(store, queries, use_kernel=True)
-    np.testing.assert_array_equal(np.asarray(f_k), [True, True, False, True])
-
-
 def test_pattern_bitmask_default_path_is_ref():
     spo = jnp.asarray([[0, 1, 2]], jnp.int32)
     pats = jnp.asarray([[0, -1, -1]], jnp.int32)
     got = ops.pattern_bitmask(spo, pats)  # default: XLA path on CPU
     np.testing.assert_array_equal(np.asarray(got), [1])
-
-
-def test_merge_probe_windowed_prefetch_variant():
-    """The scalar-prefetch (TPU production) variant matches the oracle in
-    interpret mode: per-block store windows stream via the index_map."""
-    rng = np.random.default_rng(7)
-    s = merge_join.STORE_BLOCK * 2
-    # store: s distinct sorted rows; queries: every 2nd store row (plus a few
-    # misses) -> each query block's covering range aligns to one window
-    base = np.arange(s, dtype=np.int32)
-    store = np.stack([base // 64, (base // 8) % 8, base % 8], axis=1)
-    q = store[:: s // (merge_join.QUERY_BLOCK * 2)].copy()
-    q[::5, 2] += 1  # sprinkle misses
-    q = q[np.lexsort((q[:, 2], q[:, 1], q[:, 0]))][: merge_join.QUERY_BLOCK * 2]
-
-    i_ref, f_ref = ref.merge_probe_ref(jnp.asarray(store), jnp.asarray(q))
-    firsts = q[0 :: merge_join.QUERY_BLOCK]
-    starts, _ = ref.merge_probe_ref(jnp.asarray(store), jnp.asarray(firsts))
-    win = (np.asarray(starts) // merge_join.STORE_BLOCK).astype(np.int32)
-    # precondition: every block's range fits its window (else ops.py falls back)
-    lasts = q[merge_join.QUERY_BLOCK - 1 :: merge_join.QUERY_BLOCK]
-    ends, _ = ref.merge_probe_ref(jnp.asarray(store), jnp.asarray(lasts))
-    assert np.all(np.asarray(ends) + 1 <= (win + 1) * merge_join.STORE_BLOCK)
-
-    i_k, f_k = merge_join.merge_probe_windowed(
-        jnp.asarray(store), jnp.asarray(win), jnp.asarray(q), interpret=True
-    )
-    np.testing.assert_array_equal(np.asarray(f_k).astype(bool), np.asarray(f_ref))
-    np.testing.assert_array_equal(np.asarray(i_k), np.asarray(i_ref))

@@ -8,11 +8,6 @@ import dataclasses
 
 import jax.numpy as jnp
 import numpy as np
-import pytest
-
-pytest.importorskip(
-    "hypothesis", reason="optional dev dependency (requirements-dev.txt)"
-)
 from hypothesis import HealthCheck, given, settings
 from hypothesis import strategies as st
 
@@ -28,10 +23,12 @@ from repro.core.evaluation import build_index, make_side_evaluator
 from repro.core.interest import compile_interest
 from repro.core.oracle import OracleEvaluator
 from repro.core.triples import (
+    PAD,
     apply_changeset,
     difference,
     from_numpy,
     intersection,
+    select,
     union,
 )
 
@@ -197,6 +194,43 @@ def test_set_algebra_matches_python(a, b):
     assert to_set(u) == a | b and not bool(ovf)
     assert to_set(difference(sa, sb)) == a - b
     assert to_set(intersection(sa, sb)) == a & b
+
+
+@given(
+    a=triple_set(20),
+    b=triple_set(20),
+    cap_b=st.sampled_from([1, 20, 48]),
+    cap=st.sampled_from([8, 40, 64]),
+)
+@HSETTINGS
+def test_merge_union_equals_sorting_union(a, b, cap_b, cap):
+    """The merge-path union yields exactly the rows, order, PAD tail, count
+    and overflow flag that sorting both stores' rows together does."""
+    sa, _ = from_array(jnp.asarray(np_rows(a).reshape(-1, 3)), 32)
+    sb, _ = from_array(jnp.asarray(np_rows(b).reshape(-1, 3)), cap_b)
+    got, ovf = union(sa, sb, cap)
+    want, want_ovf = from_array(jnp.concatenate([sa.spo, sb.spo]), cap)
+    np.testing.assert_array_equal(np.asarray(got.spo), np.asarray(want.spo))
+    assert int(got.n) == int(want.n)
+    assert bool(ovf) == bool(want_ovf)
+
+
+@given(
+    a=triple_set(20),
+    bits=st.integers(0, 2**32 - 1),
+    cap=st.sampled_from([4, 32]),
+)
+@HSETTINGS
+def test_select_equals_from_array_of_kept_rows(a, bits, cap):
+    """A store's kept rows without a sort == from_array over the masked
+    rows (the evaluator's former path)."""
+    sa = from_numpy(np_rows(a), 32)
+    keep = jnp.asarray(((bits >> np.arange(32)) & 1).astype(bool))
+    got, ovf = select(sa, keep, cap)
+    want, want_ovf = from_array(jnp.where(keep[:, None], sa.spo, PAD), cap)
+    np.testing.assert_array_equal(np.asarray(got.spo), np.asarray(want.spo))
+    assert int(got.n) == int(want.n)
+    assert bool(ovf) == bool(want_ovf)
 
 
 @given(v=triple_set(20), d_set=triple_set(10), a_set=triple_set(10))
