@@ -239,6 +239,7 @@ from .interest import (
     compile_interest,
     next_pow2,
 )
+from . import tracing
 from .journal import ChangesetJournal
 from .propagation import (
     ChangesetBatch,
@@ -450,6 +451,12 @@ def make_cohort_step(
              d_words,      # Fp-tuple of uint32[|U|, W] masked union words
              a_sets, bank_dev, uniq_taus, f_map, tgt_map, rhos,
              pats, lanes, active) -> (tau1s, rho1s, outs)
+
+    Each phase of both variants runs under one device scope of
+    :data:`repro.core.tracing.SCOPES` (``cohort.gather``, ``cohort.lanes``,
+    ``cohort.build_index``, ``cohort.eval_removed``, ``cohort.eval_added``,
+    ``cohort.combine``, ``cohort.unstack``), which
+    :func:`repro.core.tracing.scope_table` maps back from the trace.
     """
     eval_kw = dict(
         id_capacity=id_capacity,
@@ -479,45 +486,54 @@ def make_cohort_step(
             active: jax.Array,
         ):
             nc = lanes.shape[0]
-            rhos_s = tree_stack(list(rhos))
-            uniq_s = tree_stack(list(uniq_taus))
-            a_stack = tree_stack(list(a_sets))
-            w_stack = jnp.stack(list(d_words))
+            with jax.named_scope("cohort.gather"):
+                rhos_s = tree_stack(list(rhos))
+                uniq_s = tree_stack(list(uniq_taus))
+                a_stack = tree_stack(list(a_sets))
+                w_stack = jnp.stack(list(d_words))
 
-            a_mem = tree_gather(a_stack, f_map)
-            i_sets, ovf_i = jax.vmap(lambda a, r: union(a, r, caps.n_i))(
-                a_mem, rhos_s
-            )
-            a_bits = kops.pattern_lane_bits_batched(
-                i_sets.spo, bank_dev, lanes, active, matcher=matcher
-            )
-            # each member reads its frontier's membership-masked union
-            # words; the union STORE itself is one closed-over constant —
-            # no per-member store gather, no stacked per-frontier copies
-            d_bits = kops.lane_bits_batched(
-                jnp.take(w_stack, f_map, axis=0), lanes, active=active
-            )
-
-            tgts_u = jax.vmap(build_index)(uniq_s)
-            tgts = tree_gather(tgts_u, tgt_map)
-            taus = tree_gather(uniq_s, tgt_map)
-
-            d_res = jax.vmap(
-                lambda tgt, bits, p: eval_d(d_union, tgt, bits, p)
-            )(tgts, d_bits, pats)
-            a_res = jax.vmap(
-                lambda i_set, tgt, bits, p: eval_a(i_set, tgt, bits, p)
-            )(i_sets, tgts, a_bits, pats)
-            tau1, rho1, out = jax.vmap(
-                lambda dr, ar, t, r, o: combine_side_results(
-                    dr, ar, t, r, caps, o
+                a_mem = tree_gather(a_stack, f_map)
+                i_sets, ovf_i = jax.vmap(lambda a, r: union(a, r, caps.n_i))(
+                    a_mem, rhos_s
                 )
-            )(d_res, a_res, taus, rhos_s, ovf_i)
-            return (
-                tuple(tree_index(tau1, i) for i in range(nc)),
-                tuple(tree_index(rho1, i) for i in range(nc)),
-                tuple(tree_index(out, i) for i in range(nc)),
-            )
+            with jax.named_scope("cohort.lanes"):
+                a_bits = kops.pattern_lane_bits_batched(
+                    i_sets.spo, bank_dev, lanes, active, matcher=matcher
+                )
+                # each member reads its frontier's membership-masked union
+                # words; the union STORE itself is one closed-over constant
+                # — no per-member store gather, no stacked per-frontier
+                # copies
+                d_bits = kops.lane_bits_batched(
+                    jnp.take(w_stack, f_map, axis=0), lanes, active=active
+                )
+
+            with jax.named_scope("cohort.build_index"):
+                tgts_u = jax.vmap(build_index)(uniq_s)
+            with jax.named_scope("cohort.gather"):
+                tgts = tree_gather(tgts_u, tgt_map)
+                taus = tree_gather(uniq_s, tgt_map)
+
+            with jax.named_scope("cohort.eval_removed"):
+                d_res = jax.vmap(
+                    lambda tgt, bits, p: eval_d(d_union, tgt, bits, p)
+                )(tgts, d_bits, pats)
+            with jax.named_scope("cohort.eval_added"):
+                a_res = jax.vmap(
+                    lambda i_set, tgt, bits, p: eval_a(i_set, tgt, bits, p)
+                )(i_sets, tgts, a_bits, pats)
+            with jax.named_scope("cohort.combine"):
+                tau1, rho1, out = jax.vmap(
+                    lambda dr, ar, t, r, o: combine_side_results(
+                        dr, ar, t, r, caps, o
+                    )
+                )(d_res, a_res, taus, rhos_s, ovf_i)
+            with jax.named_scope("cohort.unstack"):
+                return (
+                    tuple(tree_index(tau1, i) for i in range(nc)),
+                    tuple(tree_index(rho1, i) for i in range(nc)),
+                    tuple(tree_index(out, i) for i in range(nc)),
+                )
 
         return step_delta
 
@@ -536,48 +552,59 @@ def make_cohort_step(
         active: jax.Array,
     ):
         nc = lanes.shape[0]
-        rhos_s = tree_stack(list(rhos))
-        uniq_s = tree_stack(list(uniq_taus))
-        d_stack = tree_stack(list(d_sets))
-        a_stack = tree_stack(list(a_sets))
-        w_stack = jnp.stack(list(d_words))
+        with jax.named_scope("cohort.gather"):
+            rhos_s = tree_stack(list(rhos))
+            uniq_s = tree_stack(list(uniq_taus))
+            d_stack = tree_stack(list(d_sets))
+            a_stack = tree_stack(list(a_sets))
+            w_stack = jnp.stack(list(d_words))
 
-        # every member reads its own frontier's composed changeset
-        d_mem = tree_gather(d_stack, f_map)
-        a_mem = tree_gather(a_stack, f_map)
-        # I_k = A_f(k) ∪ ρ_k (Def 14)
-        i_sets, ovf_i = jax.vmap(lambda a, r: union(a, r, caps.n_i))(
-            a_mem, rhos_s
-        )
-        # fused bank match + bitset-lane routing + member mask in one pass
-        # (padding members masked to zero so they see no candidates at all)
-        a_bits = kops.pattern_lane_bits_batched(
-            i_sets.spo, bank_dev, lanes, active, matcher=matcher
-        )
-        d_bits = kops.lane_bits_batched(
-            jnp.take(w_stack, f_map, axis=0), lanes, active=active
-        )
+            # every member reads its own frontier's composed changeset
+            d_mem = tree_gather(d_stack, f_map)
+            a_mem = tree_gather(a_stack, f_map)
+            # I_k = A_f(k) ∪ ρ_k (Def 14)
+            i_sets, ovf_i = jax.vmap(lambda a, r: union(a, r, caps.n_i))(
+                a_mem, rhos_s
+            )
+        with jax.named_scope("cohort.lanes"):
+            # fused bank match + bitset-lane routing + member mask in one
+            # pass (padding members masked to zero so they see no
+            # candidates at all)
+            a_bits = kops.pattern_lane_bits_batched(
+                i_sets.spo, bank_dev, lanes, active, matcher=matcher
+            )
+            d_bits = kops.lane_bits_batched(
+                jnp.take(w_stack, f_map, axis=0), lanes, active=active
+            )
 
         # one build_index(τ) per unique target replica, gathered per member
-        tgts_u = jax.vmap(build_index)(uniq_s)
-        tgts = tree_gather(tgts_u, tgt_map)
-        taus = tree_gather(uniq_s, tgt_map)
+        with jax.named_scope("cohort.build_index"):
+            tgts_u = jax.vmap(build_index)(uniq_s)
+        with jax.named_scope("cohort.gather"):
+            tgts = tree_gather(tgts_u, tgt_map)
+            taus = tree_gather(uniq_s, tgt_map)
 
-        d_res = jax.vmap(
-            lambda d_set, tgt, bits, p: eval_d(d_set, tgt, bits, p)
-        )(d_mem, tgts, d_bits, pats)
-        a_res = jax.vmap(
-            lambda i_set, tgt, bits, p: eval_a(i_set, tgt, bits, p)
-        )(i_sets, tgts, a_bits, pats)
-        tau1, rho1, out = jax.vmap(
-            lambda dr, ar, t, r, o: combine_side_results(dr, ar, t, r, caps, o)
-        )(d_res, a_res, taus, rhos_s, ovf_i)
+        with jax.named_scope("cohort.eval_removed"):
+            d_res = jax.vmap(
+                lambda d_set, tgt, bits, p: eval_d(d_set, tgt, bits, p)
+            )(d_mem, tgts, d_bits, pats)
+        with jax.named_scope("cohort.eval_added"):
+            a_res = jax.vmap(
+                lambda i_set, tgt, bits, p: eval_a(i_set, tgt, bits, p)
+            )(i_sets, tgts, a_bits, pats)
+        with jax.named_scope("cohort.combine"):
+            tau1, rho1, out = jax.vmap(
+                lambda dr, ar, t, r, o: combine_side_results(
+                    dr, ar, t, r, caps, o
+                )
+            )(d_res, a_res, taus, rhos_s, ovf_i)
         # unstack inside the trace: per-member outputs, no eager slicing
-        return (
-            tuple(tree_index(tau1, i) for i in range(nc)),
-            tuple(tree_index(rho1, i) for i in range(nc)),
-            tuple(tree_index(out, i) for i in range(nc)),
-        )
+        with jax.named_scope("cohort.unstack"):
+            return (
+                tuple(tree_index(tau1, i) for i in range(nc)),
+                tuple(tree_index(rho1, i) for i in range(nc)),
+                tuple(tree_index(out, i) for i in range(nc)),
+            )
 
     return step
 
@@ -1284,6 +1311,10 @@ class BrokerStats:
     # fires this call that fell back to the per-interest seed path after
     # the bounded overflow-retry ceiling (degraded, still bit-identical)
     degraded_fires: int = 0
+    # backend compiles during the call, persistent-cache loads and eager
+    # operations included (tracing.compile_count: process-wide, so a
+    # compile on another thread meanwhile counts too)
+    compiles: int = 0
 
 
 @dataclasses.dataclass
@@ -1511,9 +1542,9 @@ class Broker:
         self.degraded_fires = 0  # cumulative seed-path fallback fires
         self._degraded_acc = 0
         self._rejit_acc = 0.0
+        self._compiles0 = 0  # tracing.compile_count() at the call's start
         self.rejit_count = 0  # executable compiles (cohort + bank words)
         self.cohort_compiles: Dict[tuple, int] = {}  # per cohort key
-        self.words_compiles = 0  # shared D-side bank-pass compiles
 
     # -- interest manager ---------------------------------------------------
 
@@ -1768,7 +1799,8 @@ class Broker:
         """Fetch-or-compile one executable; compile time goes to rejit_s.
 
         On a miss the step is AOT-lowered against the concrete ``args`` so
-        the recorded time is pure compilation (evaluation stays outside).
+        the recorded time is pure compilation (evaluation stays outside),
+        and the executable is registered for :func:`tracing.scope_table`.
         A lowering or compile error raises here, where it happens.
         """
         fn = self._exec_cache.get(key)
@@ -1777,6 +1809,7 @@ class Broker:
             return fn
         t0 = time.perf_counter()
         fn = builder().lower(*args).compile()
+        tracing.register(fn)
         self._exec_cache[key] = fn
         while len(self._exec_cache) > self.exec_cache_max:
             self._exec_cache.popitem(last=False)
@@ -1810,40 +1843,52 @@ class Broker:
             self._service_channel()
         self._seq += 1
         cid = self._seq
-        if self.journal is not None and not self._replaying:
-            # write-ahead: the changeset is durable before any batch sees it
-            self.journal.append(
-                "ingest",
-                arrays={"removed": removed, "added": added},
-                seq=cid,
-            )
-        if not self.subs:
-            self._last_cid = cid
-            return []
-        t0 = time.perf_counter()
+        with tracing.call_span("broker.process_changeset", cid):
+            compiles0 = tracing.compile_count()
+            if self.journal is not None and not self._replaying:
+                # write-ahead: the changeset is durable before any batch
+                # sees it
+                self.journal.append(
+                    "ingest",
+                    arrays={"removed": removed, "added": added},
+                    seq=cid,
+                )
+            if not self.subs:
+                self._last_cid = cid
+                return []
+            t0 = time.perf_counter()
+            self._reset_accumulators(compiles0)
+
+            with tracing.span("broker.compose"):
+                self._apply_ingest(removed, added, cid)
+
+            now = time.perf_counter()
+            ch = self.channel
+            fired = []
+            for k, s in enumerate(self.subs):
+                batch = self._batches.get(s.since)
+                if batch is not None and s.policy.fires(
+                    batch.n_changesets, now - s.last_push_t
+                ):
+                    if ch is not None and not ch.eligible(s):
+                        continue  # quarantined / backing off: frontier pins
+                    fired.append(k)
+            results, n_passes = self._fire(fired)
+            with tracing.span("broker.fanout"):
+                self._sweep_batches(drained=bool(fired))
+            with tracing.span("broker.record_stats"):
+                self._record_stats(
+                    cid, removed, added, results, fired, n_passes, t0
+                )
+            return results
+
+    def _reset_accumulators(self, compiles0: int) -> None:
+        """Zero the per-call counters that :meth:`_record_stats` reads."""
+        self._compiles0 = compiles0
         self._rejit_acc = 0.0
         self._rows_matched_acc = self._rows_distinct_acc = 0
         self._distinct_acc = self._fanout_acc = 0
         self._degraded_acc = 0
-
-        self._apply_ingest(removed, added, cid)
-
-        now = time.perf_counter()
-        fired = []
-        for k, s in enumerate(self.subs):
-            batch = self._batches.get(s.since)
-            if batch is not None and s.policy.fires(
-                batch.n_changesets, now - s.last_push_t
-            ):
-                if self.channel is not None and not self.channel.eligible(s):
-                    continue  # quarantined / backing off: frontier pins
-                fired.append(k)
-        results, n_passes = self._fire(fired)
-        self._sweep_batches(drained=bool(fired))
-        self._record_stats(
-            cid, removed, added, results, fired, n_passes, t0
-        )
-        return results
 
     def _apply_ingest(
         self, removed: np.ndarray, added: np.ndarray, cid: int
@@ -1913,26 +1958,31 @@ class Broker:
             targets = [
                 k for k, s in enumerate(self.subs) if id(s) in wanted
             ]
-        t0 = time.perf_counter()
-        self._rejit_acc = 0.0
-        self._rows_matched_acc = self._rows_distinct_acc = 0
-        self._distinct_acc = self._fanout_acc = 0
-        self._degraded_acc = 0
-        fired = [k for k in targets if self.subs[k].since in self._batches]
-        if self.channel is not None and not self._replaying:
+        # the flush delivers up to the newest ingested changeset: its spans
+        # carry that changeset's seq
+        with tracing.call_span("broker.flush", self._last_cid):
+            t0 = time.perf_counter()
+            self._reset_accumulators(tracing.compile_count())
             fired = [
-                k for k in fired if self.channel.eligible(self.subs[k])
+                k for k in targets if self.subs[k].since in self._batches
             ]
-        results, n_passes = self._fire(fired)
-        self._sweep_batches(drained=bool(fired))
-        if fired:
-            # the committed fire consumed its own sequence tick (and
-            # journal record) inside _fire, so stats see the advanced clock
-            z = np.zeros((0, 3), np.int32)
-            self._record_stats(
-                self._seq, z, z, results, fired, n_passes, t0
-            )
-        return results
+            if self.channel is not None and not self._replaying:
+                fired = [
+                    k for k in fired if self.channel.eligible(self.subs[k])
+                ]
+            results, n_passes = self._fire(fired)
+            with tracing.span("broker.fanout"):
+                self._sweep_batches(drained=bool(fired))
+            if fired:
+                # the committed fire consumed its own sequence tick (and
+                # journal record) inside _fire, so stats see the advanced
+                # clock
+                z = np.zeros((0, 3), np.int32)
+                with tracing.span("broker.record_stats"):
+                    self._record_stats(
+                        self._seq, z, z, results, fired, n_passes, t0
+                    )
+            return results
 
     def _fire(
         self, fired: List[int]
@@ -1978,13 +2028,15 @@ class Broker:
         elif self.deferred_device_resident:
             # all fired frontiers in one evaluation: same-shape cohorts
             # stack across frontiers into one batched executable call
-            o, staged, n_passes = self._evaluate_frontiers(fronts)
+            with tracing.span("broker.evaluate"):
+                o, staged, n_passes = self._evaluate_frontiers(fronts)
             outs.update(o)
         else:
             # PR 2 baseline: one sequential pass per frontier
             n_passes = 0
             for fr in fronts:
-                o, st, passes = self._evaluate_frontiers([fr])
+                with tracing.span("broker.evaluate"):
+                    o, st, passes = self._evaluate_frontiers([fr])
                 outs.update(o)
                 staged.update(st)
                 n_passes += passes
@@ -2004,31 +2056,46 @@ class Broker:
                     self.subs[k], outs[k]
                 ):
                     acked.append(k)
-        if acked:
-            # commit point: the fire consumes one sequence tick, durably
-            # recording exactly the acked frontier advances; a crash
-            # before this append re-fires (at-least-once), a crash after
-            # it replays the evaluation without re-delivering
-            self._seq += 1
-            if self.journal is not None and not self._replaying:
-                self.journal.append(
-                    "fire",
-                    meta={
-                        "fires": [
-                            [
-                                self.subs[k].jid,
-                                self._batches[self.subs[k].since].last_id
-                                + 1,
+        with tracing.span("broker.commit"):
+            if acked:
+                # commit point: the fire consumes one sequence tick,
+                # durably recording exactly the acked frontier advances; a
+                # crash before this append re-fires (at-least-once), a
+                # crash after it replays the evaluation without
+                # re-delivering
+                self._seq += 1
+                if self.journal is not None and not self._replaying:
+                    self.journal.append(
+                        "fire",
+                        meta={
+                            "fires": [
+                                [
+                                    self.subs[k].jid,
+                                    self._batches[self.subs[k].since].last_id
+                                    + 1,
+                                ]
+                                for k in acked
                             ]
-                            for k in acked
-                        ]
-                    },
-                    seq=self._seq,
-                )
-        acked_set = set(acked)
-        self._commit_staged(
-            {k: staged[k] for k in acked if k in staged}
-        )
+                        },
+                        seq=self._seq,
+                    )
+            self._commit_staged(
+                {k: staged[k] for k in acked if k in staged}
+            )
+        with tracing.span("broker.fanout"):
+            self._fan_out(ordered, groups, set(acked), outs, results)
+        return results, n_passes
+
+    def _fan_out(
+        self,
+        ordered: List[int],
+        groups: Dict[int, List[int]],
+        acked_set: set,
+        outs: Dict[int, EvalOutputs],
+        results: List[Optional[EvalOutputs]],
+    ) -> None:
+        """Hand the acked subscribers their outputs and advance their
+        frontiers and shared-τ epochs."""
         now = time.perf_counter()
         tag_refs: Dict[int, int] = {}
         for s in self.subs:
@@ -2058,7 +2125,6 @@ class Broker:
                 for hist, e in self._epoch_intern.items()
                 if hist[0] in held
             }
-        return results, n_passes
 
     def _frontier_input(
         self, idxs: List[int], batch: ChangesetBatch
@@ -2233,303 +2299,375 @@ class Broker:
         n_retries = 0  # whole-fire overflow re-runs (bounded ceiling)
         front_of = {k: fr for fr in fronts for k in fr.idxs}
         while True:
-            for fr in fronts:
-                for k in fr.idxs:  # host-side capacity guard
-                    s = subs[k]
-                    while (
-                        fr.d_rows > s.caps.n_removed
-                        or fr.a_rows > s.caps.n_added
-                    ):
-                        s.recompile(s.caps.doubled())
-                for k in fr.idxs:  # dictionary growth guard
-                    if self.dictionary.id_capacity > subs[k].id_capacity:
-                        subs[k].recompile()
-            bank_dev = self._ensure_bank_dev()
-            n_words_p = bank_dev.shape[0] // 32
-            # deleted-side words inputs: when the subsumption bank holds
-            # virtual lanes, the words pass runs over the REAL rows only
-            # and lane_refine produces the virtual planes (parent word AND
-            # residual compare), concatenated after the real planes — the
-            # result reproduces the extended-bank word layout bit for bit,
-            # at residual cost instead of full bank width
-            bank_real = self._bank_real_dev
-            refine = self._refine_dev
-            n_words_r = bank_real.shape[0] // 32
+            with tracing.span("broker.statics"):
+                for fr in fronts:
+                    for k in fr.idxs:  # host-side capacity guard
+                        s = subs[k]
+                        while (
+                            fr.d_rows > s.caps.n_removed
+                            or fr.a_rows > s.caps.n_added
+                        ):
+                            s.recompile(s.caps.doubled())
+                    for k in fr.idxs:  # dictionary growth guard
+                        if self.dictionary.id_capacity > subs[k].id_capacity:
+                            subs[k].recompile()
+                bank_dev = self._ensure_bank_dev()
+                n_words_p = bank_dev.shape[0] // 32
+                # deleted-side words inputs: when the subsumption bank holds
+                # virtual lanes, the words pass runs over the REAL rows only
+                # and lane_refine produces the virtual planes (parent word AND
+                # residual compare), concatenated after the real planes — the
+                # result reproduces the extended-bank word layout bit for bit,
+                # at residual cost instead of full bank width
+                bank_real = self._bank_real_dev
+                refine = self._refine_dev
+                n_words_r = bank_real.shape[0] // 32
 
-            all_idx = [k for fr in fronts for k in fr.idxs]
-            d_cap = max(subs[k].caps.n_removed for k in all_idx)
-            nf = len(fronts)
-            nfp = next_pow2(nf)
+                all_idx = [k for fr in fronts for k in fr.idxs]
+                d_cap = max(subs[k].caps.n_removed for k in all_idx)
+                nf = len(fronts)
+                nfp = next_pow2(nf)
 
-            # delta-encoded frontier chain: the fired frontiers' D sides
-            # overlap (suffix composition), so build the distinct-row
-            # union + per-frontier membership bitmap and match each row
-            # ONCE; fall back to the stacked pass if containment fails
-            # (the chain proves it instead of assuming Def-6 nesting).
-            # The union is homed at its own pow2 row bucket, NOT the
-            # per-subscriber guard capacity: one store serves every
-            # member, so the whole D-side evaluation — candidate vectors,
-            # probes, pull sorts — runs at distinct-row shapes instead of
-            # F guard-capacity stores (the containment check doubles as
-            # the proof that the bucket holds every frontier's rows)
-            chain = None
-            u_cap = d_cap
-            if delta_ok:
-                base_fi = min(range(nf), key=lambda i: fronts[i].since)
-                u_cap = max(64, next_pow2(fronts[base_fi].d_rows))
-                c = build_frontier_chain(
-                    [fr.d_native() for fr in fronts], base_fi, u_cap
-                )
-                if c.covered:
-                    chain = c
-                else:
-                    u_cap = d_cap
-            if chain is not None:
-                matched = distinct = fronts[base_fi].d_rows
-            else:
-                matched = sum(fr.d_rows for fr in fronts)
-                distinct = max((fr.d_rows for fr in fronts), default=0)
-            self._rows_matched_acc += matched
-            self._rows_distinct_acc += distinct
-            self.rows_matched += matched
-            self.rows_distinct += distinct
-
-            # fused pass 1 over the deleted side. Delta chain: ONE
-            # segmented bank pass over the union rows emits every
-            # frontier's membership-masked words (padding slots' bits are
-            # simply absent from the bitmap). Stacked fallback: one bank
-            # pass per frontier, sliced per cohort; padding slots carry
-            # empty stores. The sharded path computes its words in-graph
-            # instead (block-split across shards, block-gather-stitched),
-            # so it skips this pass either way.
-            d_stores = None
-            if chain is None:
-                d_stores = [fr.d_store(d_cap) for fr in fronts]
-            d_words_all = None
-            if not sharded and chain is not None:
-                wkey = ("words-seg", u_cap, n_words_p, n_words_r, nfp, mkey)
-                if refine is None:
-                    def words_builder():
-                        return jax.jit(
-                            lambda spo, seg, b: (
-                                kops.pattern_bitmask_words_segmented(
-                                    spo, b, seg, nfp, matcher=self.matcher
-                                )
-                            )
-                        )
-
-                    wargs = (chain.union.spo, chain.seg, bank_real)
-                else:
-                    # refined planes inherit each frontier's membership
-                    # mask for free: a union row outside frontier f has
-                    # zero real bits, so its parent bit — and therefore
-                    # its refined bit — is already zero
-                    def words_builder():
-                        def f(spo, seg, b, par, res):
-                            w = kops.pattern_bitmask_words_segmented(
-                                spo, b, seg, nfp, matcher=self.matcher
-                            )
-                            wv = jax.vmap(
-                                lambda plane: kops.lane_refine(
-                                    spo, plane, par, res
-                                )
-                            )(w)
-                            return jnp.concatenate([w, wv], axis=-1)
-
-                        return jax.jit(f)
-
-                    wargs = (chain.union.spo, chain.seg, bank_real) + refine
-                miss = wkey not in self._exec_cache
-                words_fn = self._build_exec(wkey, words_builder, wargs)
-                if miss:
-                    self.words_compiles += 1
-                # (nfp, u_cap, W) — frontier fi's words over the UNION rows
-                d_words_all = words_fn(*wargs)
-            elif not sharded:
-                d_spos = tuple(st.spo for st in d_stores) + (
-                    _empty_cached(d_cap).spo,
-                ) * (nfp - nf)
-                wkey = ("words", d_cap, n_words_p, n_words_r, nfp, mkey)
-                if refine is None:
-                    def words_builder():
-                        return jax.jit(
-                            lambda spos, b: jax.vmap(
-                                lambda spo: kops.pattern_bitmask_words(
-                                    spo, b, matcher=self.matcher
-                                )
-                            )(jnp.stack(spos))
-                        )
-
-                    wargs = (d_spos, bank_real)
-                else:
-                    def words_builder():
-                        def one(spo, b, par, res):
-                            w = kops.pattern_bitmask_words(
-                                spo, b, matcher=self.matcher
-                            )
-                            return jnp.concatenate(
-                                [w, kops.lane_refine(spo, w, par, res)],
-                                axis=-1,
-                            )
-
-                        return jax.jit(
-                            lambda spos, b, par, res: jax.vmap(
-                                lambda spo: one(spo, b, par, res)
-                            )(jnp.stack(spos))
-                        )
-
-                    wargs = (d_spos, bank_real) + refine
-                miss = wkey not in self._exec_cache
-                words_fn = self._build_exec(wkey, words_builder, wargs)
-                if miss:
-                    self.words_compiles += 1
-                d_words_all = words_fn(*wargs)  # (nfp, d_cap, W)
-
-            # per-frontier added sides, cached per cohort capacity
-            a_cache: Dict[Tuple[int, int], TripleStore] = {}
-
-            def a_of(fi: int, cap: int) -> TripleStore:
-                if (fi, cap) not in a_cache:
-                    a_cache[(fi, cap)] = fronts[fi].a_store(cap)
-                return a_cache[(fi, cap)]
-
-            cohorts: Dict[tuple, List[Tuple[int, int]]] = {}
-            for fi, fr in enumerate(fronts):
-                for k in fr.idxs:
-                    s = subs[k]
-                    key = (s.shape_key, s.caps, s.id_capacity)
-                    cohorts.setdefault(key, []).append((fi, k))
-
-            # placement: sticky cohort -> device assignment, calls grouped
-            # (and therefore dispatched) by device so the mesh runs cohorts
-            # concurrently; the sharded path spans every device per call
-            cohort_items = list(cohorts.items())
-            cohort_dev: Dict[tuple, Optional[int]] = {}
-            for key, fk in cohort_items:
-                if placed:
-                    cohort_dev[key] = self.placement.assign(
-                        key, next_pow2(len(fk)), len(self._devices)
+                # delta-encoded frontier chain: the fired frontiers' D sides
+                # overlap (suffix composition), so build the distinct-row
+                # union + per-frontier membership bitmap and match each row
+                # ONCE; fall back to the stacked pass if containment fails
+                # (the chain proves it instead of assuming Def-6 nesting).
+                # The union is homed at its own pow2 row bucket, NOT the
+                # per-subscriber guard capacity: one store serves every
+                # member, so the whole D-side evaluation — candidate vectors,
+                # probes, pull sorts — runs at distinct-row shapes instead of
+                # F guard-capacity stores (the containment check doubles as
+                # the proof that the bucket holds every frontier's rows)
+                chain = None
+                u_cap = d_cap
+                if delta_ok:
+                    base_fi = min(range(nf), key=lambda i: fronts[i].since)
+                    u_cap = max(64, next_pow2(fronts[base_fi].d_rows))
+                    c = build_frontier_chain(
+                        [fr.d_native() for fr in fronts], base_fi, u_cap
                     )
+                    if c.covered:
+                        chain = c
+                    else:
+                        u_cap = d_cap
+                if chain is not None:
+                    matched = distinct = fronts[base_fi].d_rows
                 else:
-                    cohort_dev[key] = None
-            if placed:
-                cohort_items.sort(key=lambda kv: cohort_dev[kv[0]])
+                    matched = sum(fr.d_rows for fr in fronts)
+                    distinct = max((fr.d_rows for fr in fronts), default=0)
+                self._rows_matched_acc += matched
+                self._rows_distinct_acc += distinct
+                self.rows_matched += matched
+                self.rows_distinct += distinct
 
-            staged: Dict[int, Tuple[TripleStore, TripleStore]] = {}
-            outs: Dict[int, EvalOutputs] = {}
-            overflowed: List[int] = []
-            for (skey, caps, id_cap), fk in cohort_items:
-                dev = cohort_dev[(skey, caps, id_cap)]
-                device = self._devices[dev] if dev is not None else None
-                rep = subs[fk[0][1]]
-                nt = rep.plan.n_total
-                # frontier slots this cohort actually uses -> dense local
-                # slots, so the padded frontier axis stays minimal
-                fs_used = sorted({fi for fi, _ in fk})
-                fslot = {fi: i for i, fi in enumerate(fs_used)}
-                nfc = len(fs_used)
-                nfcp = next_pow2(nfc)
-                # unique target replicas (shared-τ lane groups) in this
-                # cohort; rep_fk holds each group's first (frontier, sub)
-                ugroups: List[List[int]] = []
-                rep_fk: List[Tuple[int, int]] = []
-                upos: Dict[int, int] = {}
-                seen: Dict[tuple, int] = {}
-                for fi, k in fk:
-                    s = subs[k]
-                    gk = (fi, id(s.share_tag), s.epoch)
-                    if gk not in seen:
-                        seen[gk] = len(ugroups)
-                        ugroups.append([])
-                        rep_fk.append((fi, k))
-                    upos[k] = seen[gk]
-                    ugroups[seen[gk]].append(k)
-                if self.subsume_interests:
-                    # lattice group collapse: ONE cohort slot per lane
-                    # group. Members of a group provably share plan
-                    # values, lanes, caps, τ, ρ, and frontier — that is
-                    # exactly what the (share_tag, epoch) lineage
-                    # certifies — so their slots would compute identical
-                    # results; the commit loop below fans the
-                    # representative's outputs out to every member, making
-                    # executable work a function of distinct interests and
-                    # delivery O(1) copies per interest.
-                    eval_fk = rep_fk
-                    eval_upos = {
-                        k: i for i, (_, k) in enumerate(rep_fk)
-                    }
-                else:
-                    eval_fk, eval_upos = fk, upos
-                members = [k for _, k in eval_fk]
-                f_list = [fslot[fi] for fi, _ in eval_fk]
-                nm, nu = len(members), len(ugroups)
-                ncp, nup = next_pow2(nm), next_pow2(nu)
-                self._distinct_acc += nm
-                self._fanout_acc += len(fk)
-                self.distinct_interests += nm
-                self.fanout_copies += len(fk)
-
-                d_sets = None
+                # fused pass 1 over the deleted side. Delta chain: ONE
+                # segmented bank pass over the union rows emits every
+                # frontier's membership-masked words (padding slots' bits are
+                # simply absent from the bitmap). Stacked fallback: one bank
+                # pass per frontier, sliced per cohort; padding slots carry
+                # empty stores. The sharded path computes its words in-graph
+                # instead (block-split across shards, block-gather-stitched),
+                # so it skips this pass either way.
+                d_stores = None
                 if chain is None:
-                    d_sets = tuple(
-                        TripleStore(
-                            spo=d_stores[fi].spo[: caps.n_removed],
-                            n=d_stores[fi].n,
-                        )
-                        for fi in fs_used
-                    ) + (_empty_cached(caps.n_removed, device),) * (
-                        nfcp - nfc
-                    )
-                a_sets = tuple(a_of(fi, caps.n_added) for fi in fs_used) + (
-                    _empty_cached(caps.n_added, device),
-                ) * (nfcp - nfc)
-                uniq_taus = tuple(subs[g[0]].tau for g in ugroups) + (
-                    _empty_cached(caps.tau, device),
-                ) * (nup - nu)
-                rhos_c = tuple(subs[k].rho for k in members) + (
-                    _empty_cached(caps.rho, device),
-                ) * (ncp - nm)
-                if sharded:
-                    if chain is not None:
-                        ckey = (
-                            "cohort-sh-delta", skey, caps, id_cap, ncp, nup,
-                            nfcp, n_words_p, u_cap, self._n_shards, mkey,
+                    d_stores = [fr.d_store(d_cap) for fr in fronts]
+
+                # per-frontier added sides, cached per cohort capacity
+                a_cache: Dict[Tuple[int, int], TripleStore] = {}
+
+                def a_of(fi: int, cap: int) -> TripleStore:
+                    if (fi, cap) not in a_cache:
+                        a_cache[(fi, cap)] = fronts[fi].a_store(cap)
+                    return a_cache[(fi, cap)]
+
+                cohorts: Dict[tuple, List[Tuple[int, int]]] = {}
+                for fi, fr in enumerate(fronts):
+                    for k in fr.idxs:
+                        s = subs[k]
+                        key = (s.shape_key, s.caps, s.id_capacity)
+                        cohorts.setdefault(key, []).append((fi, k))
+
+                # placement: sticky cohort -> device assignment, calls grouped
+                # (and therefore dispatched) by device so the mesh runs cohorts
+                # concurrently; the sharded path spans every device per call
+                cohort_items = list(cohorts.items())
+                cohort_dev: Dict[tuple, Optional[int]] = {}
+                for key, fk in cohort_items:
+                    if placed:
+                        cohort_dev[key] = self.placement.assign(
+                            key, next_pow2(len(fk)), len(self._devices)
                         )
                     else:
-                        ckey = (
-                            "cohort-sh", skey, caps, id_cap, ncp, nup, nfcp,
-                            n_words_p, self._n_shards, mkey,
+                        cohort_dev[key] = None
+                if placed:
+                    cohort_items.sort(key=lambda kv: cohort_dev[kv[0]])
+
+                staged: Dict[int, Tuple[TripleStore, TripleStore]] = {}
+                outs: Dict[int, EvalOutputs] = {}
+                overflowed: List[int] = []
+            with tracing.span("broker.bank_pass"):
+                d_words_all = None
+                if not sharded and chain is not None:
+                    wkey = (
+                        "words-seg", u_cap, n_words_p, n_words_r, nfp, mkey
+                    )
+                    if refine is None:
+                        def words_builder():
+                            return jax.jit(
+                                lambda spo, seg, b: (
+                                    kops.pattern_bitmask_words_segmented(
+                                        spo, b, seg, nfp, matcher=self.matcher
+                                    )
+                                )
+                            )
+
+                        wargs = (chain.union.spo, chain.seg, bank_real)
+                    else:
+                        # refined planes inherit each frontier's membership
+                        # mask for free: a union row outside frontier f has
+                        # zero real bits, so its parent bit — and therefore
+                        # its refined bit — is already zero
+                        def words_builder():
+                            def f(spo, seg, b, par, res):
+                                w = kops.pattern_bitmask_words_segmented(
+                                    spo, b, seg, nfp, matcher=self.matcher
+                                )
+                                wv = jax.vmap(
+                                    lambda plane: kops.lane_refine(
+                                        spo, plane, par, res
+                                    )
+                                )(w)
+                                return jnp.concatenate([w, wv], axis=-1)
+
+                            return jax.jit(f)
+
+                        wargs = (
+                            chain.union.spo, chain.seg, bank_real
+                        ) + refine
+                    words_fn = self._build_exec(wkey, words_builder, wargs)
+                    # (nfp, u_cap, W): frontier fi's words over the UNION
+                    # rows
+                    d_words_all = words_fn(*wargs)
+                elif not sharded:
+                    d_spos = tuple(st.spo for st in d_stores) + (
+                        _empty_cached(d_cap).spo,
+                    ) * (nfp - nf)
+                    wkey = ("words", d_cap, n_words_p, n_words_r, nfp, mkey)
+                    if refine is None:
+                        def words_builder():
+                            return jax.jit(
+                                lambda spos, b: jax.vmap(
+                                    lambda spo: kops.pattern_bitmask_words(
+                                        spo, b, matcher=self.matcher
+                                    )
+                                )(jnp.stack(spos))
+                            )
+
+                        wargs = (d_spos, bank_real)
+                    else:
+                        def words_builder():
+                            def one(spo, b, par, res):
+                                w = kops.pattern_bitmask_words(
+                                    spo, b, matcher=self.matcher
+                                )
+                                return jnp.concatenate(
+                                    [w, kops.lane_refine(spo, w, par, res)],
+                                    axis=-1,
+                                )
+
+                            return jax.jit(
+                                lambda spos, b, par, res: jax.vmap(
+                                    lambda spo: one(spo, b, par, res)
+                                )(jnp.stack(spos))
+                            )
+
+                        wargs = (d_spos, bank_real) + refine
+                    words_fn = self._build_exec(wkey, words_builder, wargs)
+                    d_words_all = words_fn(*wargs)  # (nfp, d_cap, W)
+
+            for (skey, caps, id_cap), fk in cohort_items:
+                with tracing.span("broker.statics"):
+                    dev = cohort_dev[(skey, caps, id_cap)]
+                    device = self._devices[dev] if dev is not None else None
+                    rep = subs[fk[0][1]]
+                    nt = rep.plan.n_total
+                    # frontier slots this cohort actually uses -> dense local
+                    # slots, so the padded frontier axis stays minimal
+                    fs_used = sorted({fi for fi, _ in fk})
+                    fslot = {fi: i for i, fi in enumerate(fs_used)}
+                    nfc = len(fs_used)
+                    nfcp = next_pow2(nfc)
+                    # unique target replicas (shared-τ lane groups) in this
+                    # cohort; rep_fk holds each group's first (frontier, sub)
+                    ugroups: List[List[int]] = []
+                    rep_fk: List[Tuple[int, int]] = []
+                    upos: Dict[int, int] = {}
+                    seen: Dict[tuple, int] = {}
+                    for fi, k in fk:
+                        s = subs[k]
+                        gk = (fi, id(s.share_tag), s.epoch)
+                        if gk not in seen:
+                            seen[gk] = len(ugroups)
+                            ugroups.append([])
+                            rep_fk.append((fi, k))
+                        upos[k] = seen[gk]
+                        ugroups[seen[gk]].append(k)
+                    if self.subsume_interests:
+                        # lattice group collapse: ONE cohort slot per lane
+                        # group. Members of a group provably share plan
+                        # values, lanes, caps, τ, ρ, and frontier — that is
+                        # exactly what the (share_tag, epoch) lineage
+                        # certifies — so their slots would compute identical
+                        # results; the commit loop below fans the
+                        # representative's outputs out to every member, making
+                        # executable work a function of distinct interests and
+                        # delivery O(1) copies per interest.
+                        eval_fk = rep_fk
+                        eval_upos = {
+                            k: i for i, (_, k) in enumerate(rep_fk)
+                        }
+                    else:
+                        eval_fk, eval_upos = fk, upos
+                    members = [k for _, k in eval_fk]
+                    f_list = [fslot[fi] for fi, _ in eval_fk]
+                    nm, nu = len(members), len(ugroups)
+                    ncp, nup = next_pow2(nm), next_pow2(nu)
+                    self._distinct_acc += nm
+                    self._fanout_acc += len(fk)
+                    self.distinct_interests += nm
+                    self.fanout_copies += len(fk)
+
+                    d_sets = None
+                    if chain is None:
+                        d_sets = tuple(
+                            TripleStore(
+                                spo=d_stores[fi].spo[: caps.n_removed],
+                                n=d_stores[fi].n,
+                            )
+                            for fi in fs_used
+                        ) + (_empty_cached(caps.n_removed, device),) * (
+                            nfcp - nfc
                         )
-                    (
-                        f_map_d, tgt_map_d, pats_d, lanes_d, active_d,
-                    ) = self._static_arrays(
-                        ckey, eval_fk, f_list, eval_upos, ncp, nt
-                    )
-                    parts = [
-                        self._tau_partitions(subs[g[0]], caps.tau)
-                        for g in ugroups
-                    ]
-                    pad_part = [self._empty_parts(caps.tau)] * (nup - nu)
-                    uniq_spo_sh = jnp.stack(
-                        [p[0] for p in parts] + pad_part
-                    )
-                    uniq_ops_sh = jnp.stack(
-                        [p[1] for p in parts] + pad_part
-                    )
-                    if chain is not None:
-                        # membership bits remapped to this cohort's dense
-                        # local frontier slots (they key f_map)
-                        seg_local = _seg_local_bits(
-                            chain.seg, tuple(fs_used)
+                    a_sets = tuple(
+                        a_of(fi, caps.n_added) for fi in fs_used
+                    ) + (_empty_cached(caps.n_added, device),) * (nfcp - nfc)
+                    uniq_taus = tuple(subs[g[0]].tau for g in ugroups) + (
+                        _empty_cached(caps.tau, device),
+                    ) * (nup - nu)
+                    rhos_c = tuple(subs[k].rho for k in members) + (
+                        _empty_cached(caps.rho, device),
+                    ) * (ncp - nm)
+                    if sharded:
+                        if chain is not None:
+                            ckey = (
+                                "cohort-sh-delta", skey, caps, id_cap, ncp,
+                                nup, nfcp, n_words_p, u_cap, self._n_shards,
+                                mkey,
+                            )
+                        else:
+                            ckey = (
+                                "cohort-sh", skey, caps, id_cap, ncp, nup,
+                                nfcp, n_words_p, self._n_shards, mkey,
+                            )
+                        (
+                            f_map_d, tgt_map_d, pats_d, lanes_d, active_d,
+                        ) = self._static_arrays(
+                            ckey, eval_fk, f_list, eval_upos, ncp, nt
+                        )
+                        parts = [
+                            self._tau_partitions(subs[g[0]], caps.tau)
+                            for g in ugroups
+                        ]
+                        pad_part = [self._empty_parts(caps.tau)] * (nup - nu)
+                        uniq_spo_sh = jnp.stack(
+                            [p[0] for p in parts] + pad_part
+                        )
+                        uniq_ops_sh = jnp.stack(
+                            [p[1] for p in parts] + pad_part
+                        )
+                        if chain is not None:
+                            # membership bits remapped to this cohort's dense
+                            # local frontier slots (they key f_map)
+                            seg_local = _seg_local_bits(
+                                chain.seg, tuple(fs_used)
+                            )
+                            args = (
+                                chain.union,
+                                seg_local,
+                                a_sets,
+                                bank_dev,
+                                uniq_taus,
+                                uniq_spo_sh,
+                                uniq_ops_sh,
+                                f_map_d,
+                                tgt_map_d,
+                                rhos_c,
+                                pats_d,
+                                lanes_d,
+                                active_d,
+                            )
+                            builder = (
+                                lambda nfcp=nfcp: make_sharded_cohort_step(
+                                    rep.plan, caps, id_cap, self.mesh,
+                                    axis=self._shard_axis,
+                                    n_shards=self._n_shards,
+                                    matcher=self.matcher,
+                                    delta=True, n_frontiers=nfcp,
+                                )
+                            )
+                        else:
+                            args = (
+                                d_sets,
+                                a_sets,
+                                bank_dev,
+                                uniq_taus,
+                                uniq_spo_sh,
+                                uniq_ops_sh,
+                                f_map_d,
+                                tgt_map_d,
+                                rhos_c,
+                                pats_d,
+                                lanes_d,
+                                active_d,
+                            )
+                            builder = (
+                                lambda: make_sharded_cohort_step(
+                                    rep.plan, caps, id_cap, self.mesh,
+                                    axis=self._shard_axis,
+                                    n_shards=self._n_shards,
+                                    matcher=self.matcher,
+                                )
+                            )
+                    elif chain is not None:
+                        # delta chain: ONE union store for the whole cohort at
+                        # the union's own row bucket u_cap; per-frontier
+                        # membership-masked words over the union rows (a row
+                        # outside a member's frontier carries zero bits, so
+                        # the shared store adds no candidates — no
+                        # per-frontier
+                        # slices, no per-member store gather, and the whole
+                        # D-side evaluation runs at distinct-row shapes)
+                        d_words = tuple(d_words_all[fi] for fi in fs_used)
+                        if nfcp > nfc:
+                            zero_w = jnp.zeros((u_cap, n_words_p), jnp.uint32)
+                            d_words = d_words + (zero_w,) * (nfcp - nfc)
+                        ckey = (
+                            "cohort-delta", skey, caps, id_cap, ncp, nup, nfcp,
+                            n_words_p, u_cap, mkey, dev,
+                        )
+                        (
+                            f_map_d, tgt_map_d, pats_d, lanes_d, active_d,
+                        ) = self._static_arrays(
+                            ckey, eval_fk, f_list, eval_upos, ncp, nt,
+                            device=device,
                         )
                         args = (
                             chain.union,
-                            seg_local,
+                            d_words,
                             a_sets,
-                            bank_dev,
+                            self._ensure_bank_dev(dev) if placed else bank_dev,
                             uniq_taus,
-                            uniq_spo_sh,
-                            uniq_ops_sh,
                             f_map_d,
                             tgt_map_d,
                             rhos_c,
@@ -2537,23 +2675,37 @@ class Broker:
                             lanes_d,
                             active_d,
                         )
-                        builder = (
-                            lambda nfcp=nfcp: make_sharded_cohort_step(
-                                rep.plan, caps, id_cap, self.mesh,
-                                axis=self._shard_axis,
-                                n_shards=self._n_shards,
-                                matcher=self.matcher,
-                                delta=True, n_frontiers=nfcp,
-                            )
+                        if placed:
+                            args = jax.device_put(args, device)
+                        builder = lambda: make_cohort_step(  # noqa: E731
+                            rep.plan, caps, id_cap, matcher=self.matcher,
+                            delta=True,
                         )
                     else:
+                        d_words = tuple(
+                            d_words_all[fi, : caps.n_removed] for fi in fs_used
+                        )
+                        if nfcp > nfc:
+                            zero_w = jnp.zeros(
+                                (caps.n_removed, n_words_p), jnp.uint32
+                            )
+                            d_words = d_words + (zero_w,) * (nfcp - nfc)
+                        ckey = (
+                            "cohort", skey, caps, id_cap, ncp, nup, nfcp,
+                            n_words_p, mkey, dev,
+                        )
+                        (
+                            f_map_d, tgt_map_d, pats_d, lanes_d, active_d,
+                        ) = self._static_arrays(
+                            ckey, eval_fk, f_list, eval_upos, ncp, nt,
+                            device=device,
+                        )
                         args = (
                             d_sets,
+                            d_words,
                             a_sets,
-                            bank_dev,
+                            self._ensure_bank_dev(dev) if placed else bank_dev,
                             uniq_taus,
-                            uniq_spo_sh,
-                            uniq_ops_sh,
                             f_map_d,
                             tgt_map_d,
                             rhos_c,
@@ -2561,99 +2713,22 @@ class Broker:
                             lanes_d,
                             active_d,
                         )
-                        builder = lambda: make_sharded_cohort_step(  # noqa: E731
-                            rep.plan, caps, id_cap, self.mesh,
-                            axis=self._shard_axis, n_shards=self._n_shards,
-                            matcher=self.matcher,
+                        if placed:
+                            # commit every operand to the cohort's device:
+                            # resident state (τ/ρ, statics, bank, padding) is
+                            # already there, so only the frontier slices move
+                            args = jax.device_put(args, device)
+                        builder = lambda: make_cohort_step(  # noqa: E731
+                            rep.plan, caps, id_cap, matcher=self.matcher
                         )
-                elif chain is not None:
-                    # delta chain: ONE union store for the whole cohort at
-                    # the union's own row bucket u_cap; per-frontier
-                    # membership-masked words over the union rows (a row
-                    # outside a member's frontier carries zero bits, so
-                    # the shared store adds no candidates — no per-frontier
-                    # slices, no per-member store gather, and the whole
-                    # D-side evaluation runs at distinct-row shapes)
-                    d_words = tuple(d_words_all[fi] for fi in fs_used)
-                    if nfcp > nfc:
-                        zero_w = jnp.zeros((u_cap, n_words_p), jnp.uint32)
-                        d_words = d_words + (zero_w,) * (nfcp - nfc)
-                    ckey = (
-                        "cohort-delta", skey, caps, id_cap, ncp, nup, nfcp,
-                        n_words_p, u_cap, mkey, dev,
-                    )
-                    (
-                        f_map_d, tgt_map_d, pats_d, lanes_d, active_d,
-                    ) = self._static_arrays(
-                        ckey, eval_fk, f_list, eval_upos, ncp, nt,
-                        device=device,
-                    )
-                    args = (
-                        chain.union,
-                        d_words,
-                        a_sets,
-                        self._ensure_bank_dev(dev) if placed else bank_dev,
-                        uniq_taus,
-                        f_map_d,
-                        tgt_map_d,
-                        rhos_c,
-                        pats_d,
-                        lanes_d,
-                        active_d,
-                    )
-                    if placed:
-                        args = jax.device_put(args, device)
-                    builder = lambda: make_cohort_step(  # noqa: E731
-                        rep.plan, caps, id_cap, matcher=self.matcher,
-                        delta=True,
-                    )
-                else:
-                    d_words = tuple(
-                        d_words_all[fi, : caps.n_removed] for fi in fs_used
-                    )
-                    if nfcp > nfc:
-                        zero_w = jnp.zeros(
-                            (caps.n_removed, n_words_p), jnp.uint32
+                with tracing.span("broker.cohort_dispatch"):
+                    miss = ckey not in self._exec_cache
+                    fn = self._build_exec(ckey, builder, args)
+                    if miss:
+                        self.cohort_compiles[ckey] = (
+                            self.cohort_compiles.get(ckey, 0) + 1
                         )
-                        d_words = d_words + (zero_w,) * (nfcp - nfc)
-                    ckey = (
-                        "cohort", skey, caps, id_cap, ncp, nup, nfcp,
-                        n_words_p, mkey, dev,
-                    )
-                    (
-                        f_map_d, tgt_map_d, pats_d, lanes_d, active_d,
-                    ) = self._static_arrays(
-                        ckey, eval_fk, f_list, eval_upos, ncp, nt,
-                        device=device,
-                    )
-                    args = (
-                        d_sets,
-                        d_words,
-                        a_sets,
-                        self._ensure_bank_dev(dev) if placed else bank_dev,
-                        uniq_taus,
-                        f_map_d,
-                        tgt_map_d,
-                        rhos_c,
-                        pats_d,
-                        lanes_d,
-                        active_d,
-                    )
-                    if placed:
-                        # commit every operand to the cohort's device:
-                        # resident state (τ/ρ, statics, bank, padding) is
-                        # already there, so only the frontier slices move
-                        args = jax.device_put(args, device)
-                    builder = lambda: make_cohort_step(  # noqa: E731
-                        rep.plan, caps, id_cap, matcher=self.matcher
-                    )
-                miss = ckey not in self._exec_cache
-                fn = self._build_exec(ckey, builder, args)
-                if miss:
-                    self.cohort_compiles[ckey] = (
-                        self.cohort_compiles.get(ckey, 0) + 1
-                    )
-                tau1_c, rho1_c, out_c = fn(*args)
+                    tau1_c, rho1_c, out_c = fn(*args)
                 n_passes += 1
                 if sharded:
                     for i in range(len(self._devices)):
@@ -2667,7 +2742,9 @@ class Broker:
                 for ug, g in enumerate(ugroups):
                     pos0 = members.index(g[0])
                     out = out_c[pos0]
-                    if bool(out.overflow):
+                    with tracing.span("broker.await_device"):
+                        overflow = bool(out.overflow)
+                    if overflow:
                         overflowed.extend(g)
                         continue
                     for k in g:  # shared-τ members adopt one state object
@@ -3054,5 +3131,6 @@ class Broker:
                 fanout_copies=self._fanout_acc,
                 seq=self._seq,
                 degraded_fires=self._degraded_acc,
+                compiles=tracing.compile_count() - self._compiles0,
             )
         )

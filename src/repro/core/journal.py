@@ -64,6 +64,8 @@ from typing import Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
+from . import tracing
+
 _MAGIC = b"RJNL"
 _VERSION = 1
 _HEADER = _MAGIC + struct.pack("<I", _VERSION)
@@ -254,19 +256,22 @@ class ChangesetJournal:
             raise ValueError(
                 f"journal seq must increase: got {seq}, last {self.last_seq}"
             )
-        frame = encode_record(seq, kind, meta, arrays)
-        fh = self._writer(seq)
-        fh.write(frame)
-        fh.flush()
-        if self.fsync:
-            os.fsync(fh.fileno())
+        with tracing.span("journal.append", record=seq, kind=kind):
+            frame = encode_record(seq, kind, meta, arrays)
+            fh = self._writer(seq)
+            fh.write(frame)
+            fh.flush()
+            if self.fsync:
+                with tracing.span("journal.fsync"):
+                    os.fsync(fh.fileno())
         self.last_seq = seq
         return seq
 
     def sync(self) -> None:
         if self._fh is not None:
             self._fh.flush()
-            os.fsync(self._fh.fileno())
+            with tracing.span("journal.fsync"):
+                os.fsync(self._fh.fileno())
 
     def close(self) -> None:
         if self._fh is not None:

@@ -52,7 +52,8 @@ the grid); the broker calls it on the explicit member-stacked cohort.
 
 Every kernel takes ``interpret`` as a required keyword: the ops wrappers
 pass ``interpret=not on_tpu``, so no caller can run the interpreter on the
-chip by omission.
+chip by omission. Each ``pallas_call`` is named after its wrapper, which is
+the name its operation carries in a device trace.
 """
 from __future__ import annotations
 
@@ -190,6 +191,7 @@ def triple_match_pallas(spo: jax.Array, patterns: jax.Array, *, interpret: bool)
         out_specs=col_spec,
         out_shape=jax.ShapeDtypeStruct((rows, 128), jnp.uint32),
         interpret=interpret,
+        name="triple_match_pallas",
     )(patterns, s2, p2, o2)
     return out.reshape(n)
 
@@ -225,6 +227,7 @@ def triple_match_words_pallas(
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((n_words, rows, 128), jnp.uint32),
         interpret=interpret,
+        name="triple_match_words_pallas",
     )(patterns, s2, p2, o2)
     return out.reshape(n_words, n)
 
@@ -276,6 +279,7 @@ def triple_match_words_segmented_pallas(
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((n_seg, n_words, rows, 128), jnp.uint32),
         interpret=interpret,
+        name="triple_match_words_segmented_pallas",
     )(patterns, g2, s2, p2, o2)
     return out.reshape(n_seg, n_words, n)
 
@@ -376,6 +380,7 @@ def lane_refine_pallas(
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((n_out, rows, 128), jnp.uint32),
         interpret=interpret,
+        name="lane_refine_pallas",
     )(par2, residual, w2, s2, p2, o2)
     return out.reshape(n_out, n)
 
@@ -419,5 +424,6 @@ def triple_match_lanes_pallas(
         out_specs=col_spec,
         out_shape=jax.ShapeDtypeStruct((r, rows, 128), jnp.uint32),
         interpret=interpret,
+        name="triple_match_lanes_pallas",
     )(patterns, lanes, active, s2, p2, o2)
     return out.reshape(r, n)
