@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Tuple
+from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -201,12 +201,17 @@ def searchsorted_rows(sorted_spo: jax.Array, queries: jax.Array, side: str = "le
     return lo
 
 
+def _locate(sorted_spo: jax.Array, queries: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Each query's rank among the sorted rows, and whether it is one of them."""
+    c = sorted_spo.shape[0]
+    idx = searchsorted_rows(sorted_spo, queries, side="left")
+    rows = jnp.take(sorted_spo, jnp.minimum(idx, c - 1), axis=0)
+    return idx, (idx < c) & rows_equal(rows, queries)
+
+
 def member(store: TripleStore, queries: jax.Array) -> jax.Array:
     """Boolean membership of each query row in the store."""
-    c = store.capacity
-    idx = searchsorted_rows(store.spo, queries, side="left")
-    rows = jnp.take(store.spo, jnp.minimum(idx, c - 1), axis=0)
-    return (idx < c) & rows_equal(rows, queries)
+    return _locate(store.spo, queries)[1]
 
 
 def prefix_range(store: TripleStore, prefix: jax.Array, depth: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -290,10 +295,95 @@ def union(a: TripleStore, b: TripleStore, capacity: int | None = None) -> Tuple[
     return _fit(rows, count, capacity)
 
 
-def apply_changeset(store: TripleStore, removed: TripleStore, added: TripleStore) -> Tuple[TripleStore, jax.Array]:
-    """υ(V, Δ) = (V \\ D) ∪ A  — Definition 6 (delete-first ordering)."""
-    without = difference(store, removed)
-    return union(without, added, store.capacity)
+@partial(jax.jit, static_argnames=("capacity",))
+def apply_changeset(
+    store: TripleStore,
+    removed: TripleStore | Sequence[TripleStore],
+    added: TripleStore,
+    capacity: int | None = None,
+) -> Tuple[TripleStore, jax.Array]:
+    """υ(V, Δ) = (V \\ D) ∪ A  — Definition 6 (delete-first ordering).
+
+    ``removed`` is one store or several, whose union is D. Returns what
+    ``union(difference(V, D), A, capacity)`` returns, bit for bit: the
+    sorted rows of the result, cut to ``capacity`` (default V's) with a PAD
+    tail, their count and the overflow flag.
+
+    The work follows the delta, not V: each valid row of D and A is binary
+    searched into V (|D| + |A| queries of log C steps, for C = V's
+    capacity). A removed row takes effect only if V holds it and A does
+    not re-add it; an added row that V already holds stays in place; every
+    other added row is inserted. Output slot ``k`` then takes V's row
+    ``k + off(k)``, where ``off`` is a step function with one step per
+    effective removal (+1) and insertion (−1), built by one scatter of the
+    steps and one prefix sum over ``capacity``; the inserted rows are
+    scattered in over it. So a commit costs one O(capacity) prefix sum and
+    row gather, where :func:`difference` and :func:`union` each make log C
+    rounds of C-wide gathers. Several removed stores are merged first, at
+    their own small size.
+
+    Every replica commit τ′ = (τ \\ (r ∪ r′)) ∪ a uses it
+    (:func:`repro.core.propagation.combine_side_results`, hence the
+    broker's cohort steps, single-device and sharded, and the seed
+    per-interest step).
+    """
+    capacity = store.capacity if capacity is None else capacity
+    if not isinstance(removed, TripleStore):
+        parts = list(removed)
+        removed = parts[0]
+        for part in parts[1:]:
+            removed, _ = union(
+                removed, part, removed.capacity + part.capacity
+            )
+    d_rows, a_rows = removed.spo, added.spo
+    d_pos, d_in_v = _locate(store.spo, d_rows)
+    a_pos, a_in_v = _locate(store.spo, a_rows)
+    d_in_a, d_readded = _locate(a_rows, d_rows)
+    drop = removed.valid_mask() & d_in_v & ~d_readded
+    insert = added.valid_mask() & ~a_in_v
+
+    def running_count(flags):
+        """How many flags are set before each index, and in all (the last)."""
+        return jnp.concatenate(
+            [jnp.zeros((1,), jnp.int32), jnp.cumsum(flags, dtype=jnp.int32)]
+        )
+
+    # D and A are each sorted, so the counts run in V's order too
+    drops_before = running_count(drop)
+    inserts_before = running_count(insert)
+    count = store.n - drops_before[-1] + inserts_before[-1]
+
+    # output slot of each inserted row: V's kept rows below it, plus the
+    # inserted rows before it
+    a_in_d = searchsorted_rows(d_rows, a_rows)
+    a_slot = a_pos - jnp.take(drops_before, a_in_d) + inserts_before[:-1]
+    # the first output slot past each dropped row: from there on, V's rows
+    # come one further along
+    d_slot = (
+        d_pos - drops_before[:-1] + jnp.take(inserts_before, d_in_a)
+    )
+    out_of_range = jnp.int32(capacity)
+    steps = jnp.zeros((capacity,), jnp.int32)
+    steps = steps.at[jnp.where(drop, d_slot, out_of_range)].add(
+        1, mode="drop"
+    )
+    steps = steps.at[jnp.where(insert, a_slot + 1, out_of_range)].add(
+        -1, mode="drop"
+    )
+    k = jnp.arange(capacity, dtype=jnp.int32)
+    src = k + jnp.cumsum(steps, dtype=jnp.int32)
+    rows = jnp.take(store.spo, jnp.clip(src, 0, store.capacity - 1), axis=0)
+    # each inserted row has a slot of its own, below |V| + |A|; the others
+    # aim past every slot, each at its own index
+    n_a = a_rows.shape[0]
+    ins_at = jnp.where(
+        insert,
+        a_slot,
+        store.capacity + n_a + jnp.arange(n_a, dtype=jnp.int32),
+    )
+    rows = rows.at[ins_at].set(a_rows, mode="drop", unique_indices=True)
+    rows = jnp.where((k < count)[:, None], rows, PAD)
+    return TripleStore(spo=rows, n=jnp.minimum(count, capacity)), count > capacity
 
 
 def rehome(store: TripleStore, capacity: int) -> TripleStore:

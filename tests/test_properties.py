@@ -5,7 +5,9 @@ above the maximum possible τ fan-out so the capped evaluator is exact
 (DESIGN.md §1).
 """
 import dataclasses
+from functools import partial
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from hypothesis import HealthCheck, given, settings
@@ -243,6 +245,97 @@ def test_changeset_application_def6(v, d_set, a_set):
     v1, ovf = apply_changeset(sv, sd, sa)
     assert to_set(v1) == (v - d_set) | a_set
     assert not bool(ovf)
+
+
+def _commit_cases(tau, extra, full, masks):
+    """τ (filled to its 12-row capacity when ``full``) and r, r', a drawn by
+    bit masks from τ's rows and rows absent from τ, so that removed rows
+    miss τ, r and r' share rows, added rows are in τ or re-add removed
+    rows, and any delta may be empty."""
+    tau = set(tau)
+    if full:
+        universe = (
+            (s, p, o) for s in SUBJ for p in PRED for o in OBJ
+        )
+        for row in universe:
+            if len(tau) == 12:
+                break
+            tau.add(row)
+    pool = sorted(tau) + sorted(set(extra) - tau)
+    r, r2, a = (
+        {row for i, row in enumerate(pool) if m >> i & 1} for m in masks
+    )
+    return tau, r, r2, a
+
+
+@partial(jax.jit, static_argnums=4)
+def _commit_by_whole_stores(tau, r, r2, a, cap):
+    return union(difference(difference(tau, r), r2), a, cap)
+
+
+def _assert_same_store(got, want):
+    (g, g_ovf), (w, w_ovf) = got, want
+    np.testing.assert_array_equal(np.asarray(g.spo), np.asarray(w.spo))
+    np.testing.assert_array_equal(np.asarray(g.n), np.asarray(w.n))
+    np.testing.assert_array_equal(np.asarray(g_ovf), np.asarray(w_ovf))
+
+
+@given(
+    tau=triple_set(12),
+    extra=triple_set(6),
+    full=st.booleans(),
+    masks=st.tuples(*[st.integers(0, 2**18 - 1)] * 3),
+    cap_vs_result=st.sampled_from([-2, 0, 3]),
+)
+@HSETTINGS
+def test_delta_commit_equals_whole_store_commit(
+    tau, extra, full, masks, cap_vs_result
+):
+    """τ' = ((τ \\ r) \\ r') ∪ a by the changed rows alone: the same rows,
+    PAD tail, count and overflow flag as the whole-store composition, with
+    the output capacity below, at or above the result's size."""
+    tau, r, r2, a = _commit_cases(tau, extra, full, masks)
+    cap = max(1, len(((tau - r) - r2) | a) + cap_vs_result)
+    args = (
+        from_numpy(np_rows(tau), 12),
+        from_numpy(np_rows(r), 24),
+        from_numpy(np_rows(r2), 24),
+        from_numpy(np_rows(a), 24),
+    )
+    want = _commit_by_whole_stores(*args, cap)
+    tau_s, r_s, r2_s, a_s = args
+    _assert_same_store(apply_changeset(tau_s, (r_s, r2_s), a_s, cap), want)
+    assert bool(want[1]) == (len(((tau - r) - r2) | a) > cap)
+
+
+@given(
+    tau=triple_set(8),
+    extras=st.tuples(triple_set(6), triple_set(6)),
+    masks=st.tuples(*[st.integers(0, 2**18 - 1)] * 6),
+)
+@HSETTINGS
+def test_delta_commit_under_vmap(tau, extras, masks):
+    """The commit vmapped over two cohort members, one τ of at most 8 rows
+    and one full at 12, each with its own delta, equals each member's
+    whole-store composition."""
+    stores = []
+    for full, extra, m in zip((False, True), extras, (masks[:3], masks[3:])):
+        member_tau, r, r2, a = _commit_cases(tau, extra, full, m)
+        stores.append((
+            from_numpy(np_rows(member_tau), 12),
+            from_numpy(np_rows(r), 24),
+            from_numpy(np_rows(r2), 24),
+            from_numpy(np_rows(a), 24),
+        ))
+    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *stores)
+    got = jax.vmap(lambda t, r, r2, a: apply_changeset(t, (r, r2), a, 16))(
+        *stacked
+    )
+    for i, args in enumerate(stores):
+        _assert_same_store(
+            jax.tree.map(lambda x: x[i], got),
+            _commit_by_whole_stores(*args, 16),
+        )
 
 
 @given(a=triple_set(30))
